@@ -35,7 +35,6 @@ from repro.configs import SERF_AUDIO as cfg
 from repro.core.graph import PipelineGraph
 from repro.data.loader import audio_batch_maker
 from repro.kernels.fused_tail import kernel as FTK
-from repro.kernels.stft_dft.kernel import PAD_OUT
 from benchmarks import roofline
 from benchmarks.util import table, save_json
 
@@ -46,7 +45,7 @@ def boundary_bytes(R, S, window, hop, hpf=False):
     Staged: every stage output materialises — the gathered (R,S) f32
     batch, the optional hpf (R,S) f32, the (R,S_pad) f32 pad, the raw
     (R,Fv,bins) c64 spectrum, the filtered (R,Fv,bins) c64 spectrum, and
-    the (R,S) f32 resynthesis. Fused: the kernel's packed (R,F,PAD_OUT)
+    the (R,S) f32 resynthesis. Fused: the kernel's packed (R,F,2*KP)
     f32 spectrum plus the same (R,S) f32 resynthesis out of `finish`."""
     _, S_pad, F, Fv = FTK.tail_geometry(S, window, hop)
     bins = window // 2 + 1
@@ -57,7 +56,7 @@ def boundary_bytes(R, S, window, hop, hpf=False):
     staged += R * Fv * bins * 8        # raw spectrum (complex64)
     staged += R * Fv * bins * 8        # gain-filtered spectrum
     staged += R * S * 4                # istft output
-    fused = R * F * PAD_OUT * 4        # packed filtered spectrum
+    fused = R * F * 2 * FTK.spectrum_lanes(bins) * 4   # packed spectrum
     fused += R * S * 4                 # istft output (finish)
     return staged, fused
 

@@ -41,16 +41,16 @@ def fused_tail_record(R, S, window=256, hop=128, hpf=False, hpf_taps=129):
     against the kernel's true HBM traffic (gathered rows in, packed
     filtered spectrum out — the VMEM-resident intermediates move nothing).
     kind="pipeline" so the f32 byte counts skip the bf16 correction."""
-    from repro.kernels.fused_tail.kernel import tail_geometry
-    from repro.kernels.stft_dft.kernel import PAD_OUT
+    from repro.kernels.fused_tail.kernel import spectrum_lanes, tail_geometry
     _, S_pad, F, _ = tail_geometry(S, window, hop)
     bins = window // 2 + 1
-    flops = 2 * R * F * window * PAD_OUT          # matmul DFT
+    out = 2 * spectrum_lanes(bins)                # packed [re | im] lanes
+    flops = 2 * R * F * window * out              # matmul DFT
     if hpf:
         flops += 2 * R * S * hpf_taps             # FIR tap chain
     flops += R * F * bins * 40                    # MMSE recurrence (approx)
-    bytes_ = R * S * 4 + window * PAD_OUT * 4     # rows + basis in
-    bytes_ += R * F * PAD_OUT * 4                 # packed spectrum out
+    bytes_ = R * S * 4 + window * out * 4         # rows + basis in
+    bytes_ += R * F * out * 4                     # packed spectrum out
     return {"kind": "pipeline", "flops_per_device": flops,
             "bytes_per_device": bytes_, "collective_bytes_per_device": 0,
             "n_devices": 1}

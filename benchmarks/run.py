@@ -38,8 +38,9 @@ def smoke(chaos_seed=None):
     SIGKILL mid-request — every surviving request must resolve exactly
     once, bit-identical to two_phase, with the killed worker's lease
     redelivered. Finally the FUSED-TAIL gate: two_phase with the fused
-    single-pass survivor tail vs the staged per-stage tail, bit-identical
-    masks + cleaned audio in ref AND interpret backends, pad rows zero.
+    single-pass survivor tail vs the staged per-stage tail, bitwise in the
+    ref backend, equal masks and cleaned audio within backend.REF_TOL of
+    ref in the interpret backend, pad rows zero.
     Then the OBSERVABILITY gate: the driver over 2 real proc workers
     with --trace + --telemetry must yield a schema-valid Chrome trace with
     worker events parented under the master's run span and exactly one
@@ -426,9 +427,11 @@ def _serving_smoke(np, cfg, Preprocessor):
 
 def _fused_smoke(np, cfg, Preprocessor):
     """Fused-survivor-tail gate: two_phase with the fused single-pass tail
-    vs two_phase with the staged per-stage tail on the same tiny stream —
-    survivor masks AND cleaned audio bit-identical in both the ref oracle
-    and interpret-kernel backends, and pad-index slots must come through
+    vs two_phase with the staged per-stage tail on the same tiny stream.
+    In the ref backend both compose the float32 reference: masks AND
+    cleaned audio bitwise equal. In the interpret backend (the kernels'
+    own arithmetic) both must give the reference's survivor sets and its
+    cleaned audio within backend.REF_TOL. Pad-index slots must come through
     the fused kernel as exactly-zero rows (fill-gather semantics)."""
     import jax.numpy as jnp
     from repro.core.graph import PipelineGraph
@@ -438,6 +441,7 @@ def _fused_smoke(np, cfg, Preprocessor):
     t0 = time.time()
     make = audio_batch_maker(seed=9, batch_long_chunks=1)
     stream = [(w, (make(w)[0], None)) for w in range(2)]
+    ref = None
     for mode in ("ref", "interpret"):
         with backend.use(mode):
             staged = Preprocessor(cfg, plan="two_phase", pad_multiple=1,
@@ -445,10 +449,20 @@ def _fused_smoke(np, cfg, Preprocessor):
             fused = Preprocessor(cfg, plan="two_phase", pad_multiple=1,
                                  fuse_tail=True)
             assert fused.plan.fuse_tail is True
+            got = []
             for a, b in zip(staged.run(stream), fused.run(stream)):
-                np.testing.assert_array_equal(np.asarray(a.det.keep),
-                                              np.asarray(b.det.keep))
-                np.testing.assert_array_equal(a.cleaned, b.cleaned)
+                keep = np.asarray(a.det.keep)
+                np.testing.assert_array_equal(keep, np.asarray(b.det.keep))
+                got.append((keep, a.cleaned, b.cleaned))
+        if ref is None:                 # the ref backend runs first
+            ref = got
+            for _, a, b in got:
+                np.testing.assert_array_equal(a, b)
+            continue
+        for (keep, a, b), (want_keep, want, _) in zip(got, ref):
+            np.testing.assert_array_equal(keep, want_keep)
+            np.testing.assert_allclose(a, want, **backend.REF_TOL)
+            np.testing.assert_allclose(b, want, **backend.REF_TOL)
     # pad rows: out-of-range survivor slots -> exactly-zero output rows
     g = PipelineGraph(cfg)
     rng = np.random.RandomState(0)
@@ -458,8 +472,9 @@ def _fused_smoke(np, cfg, Preprocessor):
     with backend.use("ref"):
         out = np.asarray(g.tail_indexed_fused(wave, idx))
     assert not out[1].any() and out[0].any() and out[2].any()
-    print(f"plan fused-tail OK: fused == staged bit-identical (ref + "
-          f"interpret), pad rows zero, in {time.time() - t0:.1f}s")
+    print(f"plan fused-tail OK: fused == staged bitwise in ref, both "
+          f"within {backend.REF_TOL} of ref in interpret, pad rows zero, in "
+          f"{time.time() - t0:.1f}s")
 
 
 def _obs_smoke():

@@ -37,10 +37,12 @@ Mask semantics follow the paper: cicada gates on ~rain, silence gates on
 """
 from __future__ import annotations
 
+import math
 from dataclasses import dataclass, replace
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.core import detect as D
 from repro.core import indices as I
@@ -128,6 +130,49 @@ def _indices(state, cfg):
 
 
 _MASK_KEYS = ("rain", "silence", "keep")
+# per-chunk state fields a phase hands back (spectra and indices stay local)
+_OUT_KEYS = ("wave", "cicada") + _MASK_KEYS
+
+
+def chunk_split(rules):
+    """(mesh axes, device count) that `rules` split the "chunks" axis
+    over; (None, 1) without a mesh or a chunk rule."""
+    axes = rules.spec("chunks")[0] if rules.mesh is not None else None
+    if axes is None:
+        return None, 1
+    names = axes if isinstance(axes, tuple) else (axes,)
+    return axes, math.prod(rules.mesh.shape[a] for a in names)
+
+
+def check_split(n_chunks, rules):
+    """Raise unless a batch of `n_chunks` splits evenly over the chunk
+    axes of `rules` (pad the batch to a multiple: `pad_multiple=`)."""
+    axes, n = chunk_split(rules)
+    if n_chunks % n:
+        raise ValueError(f"a batch of {n_chunks} chunks does not split over "
+                         f"the {n} devices of the chunk axes {axes}")
+
+
+def per_chunk(rules, fn, *args, replicated=()):
+    """Run `fn` on every device's slice of the chunk batch.
+
+    Without a mesh (or on one device) this is `fn(*args)`. When `rules`
+    shard "chunks" over several devices, XLA cannot partition the Pallas
+    kernels inside the stage chain, so the chain runs under `shard_map`:
+    dim 0 of each argument is split over the chunk axes (arguments whose
+    position is in `replicated` go whole to every device) and every output
+    leaf is reassembled along dim 0. The stages then see NULL_RULES."""
+    axes, n = chunk_split(rules)
+    if n == 1:
+        return fn(*args)
+    for i, a in enumerate(args):
+        if i not in replicated:
+            check_split(a.shape[0], rules)
+    mesh = rules.mesh
+    in_specs = tuple(P() if i in replicated else P(axes)
+                     for i in range(len(args)))
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=P(axes), check_vma=False)(*args)
 
 
 # ----------------------------------------------------------------- stages
@@ -454,6 +499,15 @@ class PipelineGraph:
             state = st.apply(state, rules)
         return state
 
+    def _run_sharded(self, stages, wave, rules):
+        """`_run` from a bare wave, per device under `per_chunk`; returns
+        the per-chunk fields of the final state."""
+        def local(w):
+            state = self._run(stages, {"wave": w}, NULL_RULES if
+                              rules.mesh is not None else rules)
+            return {k: state[k] for k in _OUT_KEYS if k in state}
+        return per_chunk(rules, local, wave)
+
     def _outputs(self, state) -> PipelineOutput:
         wave = state["wave"]
         n = wave.shape[0]
@@ -478,14 +532,14 @@ class PipelineGraph:
         point — wave5 is not yet denoised. A graph that declares NO
         removal point has no phase split: this runs the whole chain
         (including any denoise stages)."""
-        state = self._run(self.stages[:self._cut()], {"wave": audio}, rules)
-        return self._outputs(state)
+        return self._outputs(self._run_sharded(self.stages[:self._cut()],
+                                               audio, rules))
 
     def tail(self, wave, rules=NULL_RULES):
         """Phase B: the survivor stages past the first removal point,
         applied to a (compacted) chunk batch."""
-        state = self._run(self.stages[self._cut():], {"wave": wave}, rules)
-        return state["wave"]
+        return self._run_sharded(self.stages[self._cut():], wave,
+                                 rules)["wave"]
 
     def tail_indexed(self, wave, idx, rules=NULL_RULES):
         """Phase B with DEVICE-RESIDENT compaction: gather the survivor
@@ -519,22 +573,25 @@ class PipelineGraph:
         """`tail_indexed` through the single fused Pallas pass
         (kernels/fused_tail): gather-compact + [HPF] + STFT + MMSE gain
         happen in one VMEM-resident kernel, with only the iSTFT outside.
-        Bit-identical to `tail_indexed` per backend mode; only valid when
-        `fused_tail_spec` is not None."""
+        Equal to `tail_indexed` within float32 rounding (bitwise in ref
+        mode); only valid when `fused_tail_spec` is not None."""
         spec = self.fused_tail_spec
         if spec is None:
             raise GraphValidationError(
                 f"post-removal stages {self.names[self._cut():]} are not "
                 "the canonical fused tail; use tail_indexed")
-        wave = rules.constrain(wave, "chunks", None)
-        return fused_tail_ops.fused_tail(wave, idx, self.cfg,
-                                         hpf=spec["hpf"])
+        # on a mesh every device cleans an equal share of the survivor
+        # list out of the whole batch (gathered to each device once), so
+        # survivor imbalance between the devices' own chunks costs nothing
+        return per_chunk(
+            rules, lambda w, i: fused_tail_ops.fused_tail(
+                w, i, self.cfg, hpf=spec["hpf"]),
+            wave, idx, replicated=(0,))
 
     def fused(self, audio, rules=NULL_RULES) -> PipelineOutput:
         """Single-trace mode: the whole chain, removed chunks masked but
         still computed (the paper's no-early-exit baseline)."""
-        state = self._run(self.stages, {"wave": audio}, rules)
-        out = self._outputs(state)
+        out = self._outputs(self._run_sharded(self.stages, audio, rules))
         masked = jnp.where(out.keep[:, None], out.wave5, 0.0)
         return PipelineOutput(wave5=masked, keep=out.keep, rain=out.rain,
                               silence=out.silence, cicada15=out.cicada15,

@@ -112,8 +112,9 @@ is the canonical fused tail — `("mmse",)` or `("hpf", "mmse")`, per
 staged `tail_idx` phase for `tail_idx_fused`: one Pallas pass
 (`kernels/fused_tail`) doing gather-compact + [HPF] + STFT + MMSE gain
 with power/spec/gain tiles VMEM-resident, only the iSTFT outside. Keyed
-per pow2 survivor bucket in the same CompileCache, same donation rules,
-bit-identical per backend mode. `fuse_tail=` overrides: None (default)
+per pow2 survivor bucket in the same CompileCache, same donation rules;
+bitwise equal to the staged tail in ref mode and within float32 rounding
+of it in the kernel modes. `fuse_tail=` overrides: None (default)
 auto-engages on a canonical tail, False forces the staged path, True
 demands fusion and raises on a non-canonical tail. Any other survivor
 chain silently falls back to the staged per-stage dispatches.
@@ -211,7 +212,7 @@ import numpy as np
 
 from repro.core import scheduler as SCHED
 from repro.core.graph import (GraphValidationError, PipelineGraph,
-                              PipelineOutput)
+                              PipelineOutput, check_split)
 from repro.data.loader import ShardedLoader, make_shard_pool
 from repro.data.queue import WorkQueue
 from repro.dist.data_plane import StoreDataPlane
@@ -222,6 +223,7 @@ from repro.ft.failure import StragglerDetector
 from repro.kernels import backend
 from repro.obs import metrics as obs_metrics
 from repro.obs import tracing as obs_tracing
+from repro.runtime import refuse_worker_processes
 from repro.store import ChunkStore, RunJournal, content_key
 
 
@@ -282,6 +284,16 @@ def _phase_fn(kind, graph: PipelineGraph, rules):
     if kind == "tail_idx_fused":
         return lambda w, i: graph.tail_indexed_fused(w, i, rules)
     raise KeyError(f"unknown phase {kind!r}")
+
+
+def _to_device(chunks, rules):
+    """Host batch -> device, split over the chunk axes when the rules
+    carry a mesh; raises if the batch does not divide over them."""
+    sharding = rules.sharding("chunks", *([None] * (np.ndim(chunks) - 1)))
+    if sharding is None:
+        return jnp.asarray(chunks)
+    check_split(np.shape(chunks)[0], rules)
+    return jax.device_put(chunks, sharding)
 
 
 def _jitted(kind, graph, rules, donate=(), shape=None):
@@ -553,7 +565,7 @@ class TwoPhasePlan(ExecutionPlan):
                                            timings))
 
     def __call__(self, audio) -> BatchResult:
-        x = jnp.asarray(audio)
+        x = _to_device(audio, self.rules)
         return self._finish(self.detect(x), src_bytes=int(x.nbytes))
 
 
@@ -601,7 +613,7 @@ class AsyncPlan(TwoPhasePlan):
             t0 = time.perf_counter()
             with obs_tracing.span("detect_dispatch", wid=wid):
                 owned = not isinstance(chunks, jax.Array)
-                x = jnp.asarray(chunks)
+                x = _to_device(chunks, self.rules)
                 det = self._detect_donated(x) if owned and self.donate \
                     else self.detect(x)               # async dispatch
                 if hasattr(det.keep, "copy_to_host_async"):
@@ -1019,6 +1031,8 @@ class ShardedPlan(TwoPhasePlan):
                 "backend_mode": backend.get_mode()}
 
     def _run_proc(self, pool, queue):
+        refuse_worker_processes(
+            f"ShardedPlan(transport={self._transport_kind()!r})")
         make_item = pool[0].make_item
         extras = {}                 # wid -> labels/_StreamMeta, master-side
 

@@ -275,6 +275,8 @@ class ProcTransport:
         `QueueService.reserve`); pass None for a pure late joiner."""
         if self.address is None:
             raise RuntimeError("serve() first: workers need an address")
+        from repro.runtime import refuse_worker_processes
+        refuse_worker_processes("spawn_worker")
         import repro
         # repro may be a namespace package (no __init__.py): resolve the
         # directory ABOVE the package from its path entries

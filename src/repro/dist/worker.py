@@ -196,6 +196,8 @@ def main(argv=None):
     ap.add_argument("--poll-s", type=float, default=0.05,
                     help="sleep between empty lease polls")
     args = ap.parse_args(argv)
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     run_worker(args.master, args.shard, lease_items=args.lease_items,
                poll_s=args.poll_s)
     return 0

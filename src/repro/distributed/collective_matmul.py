@@ -18,7 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def ag_matmul(x, w, mesh, axis="model"):
@@ -34,10 +34,8 @@ def ag_matmul(x, w, mesh, axis="model"):
         # x_blk: (M/n, K); w_blk: (K, N/n)
         idx = jax.lax.axis_index(axis)
         M_blk = x_blk.shape[0]
-        # pvary marks the accumulator varying over the ring axis; older jax
-        # has no pvary and no varying-axes check either, so identity is safe
-        pvary = getattr(jax.lax, "pvary", lambda v, axes: v)
-        out = pvary(
+        # pvary marks the accumulator varying over the ring axis
+        out = jax.lax.pvary(
             jnp.zeros((M_blk * n, w_blk.shape[1]), x_blk.dtype), (axis,))
 
         def body(i, carry):

@@ -14,8 +14,11 @@ modes: when a two-phase-family plan detects the canonical post-removal
 chain ([hpf ->] mmse), its survivor dispatch becomes one fused pass whose
 backend follows resolve()/matmul_dft() exactly like the per-stage ops it
 replaces — ref oracle on CPU auto, pallas/interpret kernel when forced,
-bf16 matmul-DFT twin under "matmul" — so fused and staged stay
-bit-identical within every mode.
+bf16 matmul-DFT twin under "matmul".
+
+A chip run must not silently take another path: `repro.runtime
+.require_chip` refuses to start unless the backend is a TPU and resolve()
+gives (True, False).
 """
 from __future__ import annotations
 
@@ -26,6 +29,15 @@ import jax
 
 _MODE = os.environ.get("REPRO_KERNEL_BACKEND", "auto")
 _VALID = ("auto", "pallas", "interpret", "ref", "matmul")
+
+# How far the Pallas kernels' cleaned audio may stray from the float32
+# "ref" path. The kernels run the DFT and FIR as float32 MXU matmuls
+# (precision=HIGHEST) that sum in another order than XLA's FFT and conv;
+# the largest gap seen is 1.91e-6 on a TPU v5e over 543 cleaned 5 s chunks
+# and 1.8e-6 in interpret mode on the CPU. 1e-5 sits 5x above both. A
+# kernel whose dots fall back to one bf16 MXU pass is off by ~1e-4 on
+# audio of ~0.1 scale and fails it; a wrong bin, frame or tap is O(0.1).
+REF_TOL = dict(rtol=0.0, atol=1e-5)
 
 
 def set_mode(mode: str):

@@ -41,5 +41,6 @@ def fir_ref(x, taps, stride=1):
     out = jax.lax.conv_general_dilated(
         xp[:, None, :], jnp.flip(taps)[None, None, :],
         window_strides=(stride,), padding="VALID",
-        dimension_numbers=("NCH", "OIH", "NCH"))
+        dimension_numbers=("NCH", "OIH", "NCH"),
+        precision=jax.lax.Precision.HIGHEST)   # float32 on every backend
     return out[:, 0, :x.shape[1] // stride]

@@ -8,35 +8,34 @@ One `pallas_call` over grid (survivor_rows,) performs, per grid step:
                    survivor row this step needs straight out of the full
                    pre-denoise batch — the compacted batch is never
                    materialised in HBM. Out-of-range pad indices are
-                   clamped for the DMA and zero-masked in VMEM, preserving
-                   `jnp.take(mode="fill")`'s zero-row pad convention
-                   bit-for-bit.
-  FIR high-pass    (optional) fir_hpf's per-tile tap accumulation, run as
-                   a lax.scan over FIR_TILE spans so the chain compiles
-                   once and its output materialises — the fused row is
-                   bitwise the staged `fir_pallas` row in every mode.
-  STFT             the 50%-overlap even/odd contiguous-reshape framing and
-                   windowed matmul-DFT of stft_dft's kernel, `frame_block`
-                   FRAME_TILE tiles per MXU dispatch (row-tiling a dot is
-                   bitwise-stable, so the block size is a pure perf knob).
+                   clamped for the DMA and their output is written as
+                   zeros without computing, preserving
+                   `jnp.take(mode="fill")`'s zero-row pad convention.
+  FIR high-pass    (optional) fir_hpf's banded-matmul formulation at
+                   stride 1, chunk by chunk into a VMEM scratch row.
+  STFT             stft_dft's 50%-overlap framing (rows f and f + 1 of
+                   the 128-lane row view) and windowed matmul-DFT,
+                   `frame_block` FRAME_TILE tiles of frames per dispatch.
   MMSE-STSA        the sequential-over-frames decision-directed recurrence
-                   of mmse_stsa's kernel (same A&S i0e/i1e polynomials,
-                   same clip points), `bin_tile` lanes per scan.
-  gain apply       the filtered spectrum re*g / im*g is written packed;
-                   power, noise, and gain tiles never leave VMEM.
+                   of mmse_stsa's kernel (`frame_gain`), one (1, KP) frame
+                   row of every bin per step.
+  gain apply       the filtered spectrum re*g / im*g is written in place
+                   into the output block; power, noise and gain never
+                   leave VMEM.
+
+Layout: the row of S samples is viewed as (F + 8, 128) — the STFT's padded
+length plus one 8-row block, so frame f + 1's rows exist for every frame —
+and every value in the kernel is 2-D with 128-lane-aligned slices. The
+spectrum is packed as [re | im], each half padded from `bins` to KP lanes
+(KP = bins rounded up to 128), so both halves start on a lane tile and a
+frame row is read and written whole (Mosaic has no dynamic-row load of a
+lane slice).
 
 Only the inverse-DFT overlap-add resynthesis stays OUTSIDE the kernel
-(`finish`): the staged pipeline's iSTFT is irfft-based in every non-matmul
-mode (stft_dft.ops.istft), and an in-kernel matmul iDFT could not be
-bit-identical to it — so the kernel hands the one (rows, F, PAD_OUT)
-filtered spectrum across the HBM boundary instead of the gathered wave,
-the raw spectrum, the power, the noise and the gain arrays the staged tail
-streams between its dispatches.
-
-VMEM per grid step at the SERF shape (S5=110250 -> S_pad=114816, F=896):
-row ~0.9 MB + frames 0.9 MB + basis 0.4 MB + packed out 1.4 MB + power/
-gain ~1.8 MB — ~5.5 MB, comfortably inside the ~16 MB/core budget the
-autotuner (ops.py) validates candidates against.
+(`finish`, the irfft-based istft the staged tail runs), so the kernel hands
+the one (rows, F, 2 * KP) filtered spectrum across the HBM boundary instead
+of the gathered wave, the raw spectrum, the power, the noise and the gain
+arrays the staged tail streams between its dispatches.
 """
 from __future__ import annotations
 
@@ -48,11 +47,10 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.fir_hpf.kernel import OUT_TILE as FIR_TILE
+from repro.kernels.fir_hpf.kernel import LANES, prev_rows, toeplitz_taps
 from repro.kernels.fir_hpf.ref import highpass_taps
-from repro.kernels.mmse_stsa.kernel import i0e_poly, i1e_poly
-from repro.kernels.mmse_stsa.ref import GAMMA_MAX, SQRTPI_2, XI_MIN
-from repro.kernels.stft_dft.kernel import FRAME_TILE, PAD_OUT, dft_basis
+from repro.kernels.mmse_stsa.kernel import frame_gain
+from repro.kernels.stft_dft.kernel import FRAME_TILE, HIGHEST, dft_basis_np
 from repro.kernels.stft_dft.ref import istft_ref
 
 
@@ -66,153 +64,160 @@ def tail_geometry(S, window=256, hop=128):
             (S - window) // hop + 1)
 
 
-def _fused_tail_kernel(idx_ref, x_ref, basis_ref, o_ref, *, n_rows_in, S,
-                       window, hop, bins, alpha, gain_floor, noise_frames,
-                       taps, frame_block, bin_tile):
-    r = pl.program_id(0)
-    row = x_ref[0].astype(jnp.float32)
+def spectrum_lanes(bins):
+    """KP: the lanes each of the re / im halves of the packed spectrum
+    takes — `bins` rounded up to whole 128-lane tiles."""
+    return -(-bins // LANES) * LANES
 
-    if taps is not None:
-        # causal FIR, stride 1: y[n] = sum_i g[i] * xp[n+i] with g the
-        # flipped taps — fir_hpf._fir_kernel's exact per-FIR_TILE chain,
-        # run as a lax.scan over tiles. The loop is load-bearing for
-        # bit-identity, not style: an unrolled whole-row tap chain is one
-        # giant elementwise graph that XLA duplicates per consumer (the
-        # framing slices below), and each duplicate contracts mul+add to
-        # FMA differently. The scan compiles the chain ONCE and
-        # materialises its output, so every consumer reads the same bits
-        # the staged fir_pallas produced.
-        T = len(taps)
-        g = np.asarray(taps, np.float32)[::-1]
-        n_ft = -(-S // FIR_TILE)
-        xp = jnp.concatenate([jnp.zeros((T - 1,), jnp.float32), row,
-                              jnp.zeros((n_ft * FIR_TILE - S,), jnp.float32)])
-        spans = jnp.stack([jax.lax.slice(xp, (t * FIR_TILE,),
-                                         (t * FIR_TILE + FIR_TILE + T - 1,))
-                           for t in range(n_ft)])
 
-        def fir_tile(carry, span):
-            acc = jnp.zeros((FIR_TILE,), jnp.float32)
-            for i in range(T):
-                acc = acc + g[i] * span[i:i + FIR_TILE]
-            return carry, acc
+def split_basis(window, kp):
+    """(window, 2 * kp) windowed DFT basis: [cos | pad | -sin | pad]."""
+    bins = window // 2 + 1
+    b = dft_basis_np(window)
+    out = np.zeros((window, 2 * kp), np.float32)
+    out[:, :bins] = b[:, :bins]
+    out[:, kp:kp + bins] = b[:, bins:2 * bins]
+    return jnp.asarray(out)
 
-        _, ys = jax.lax.scan(fir_tile, 0, spans)
-        row = ys.reshape(-1)[:S]
 
-    # fill-gather semantics: the BlockSpec index_map clamped this step's
-    # row id for the DMA; pad slots (idx >= n_rows_in) become zero rows.
-    # Masked AFTER the (linear) FIR — FIR(0)=0, so values match the
-    # staged take-then-filter order — keeping the predicate out of the
-    # tap chain's fusion context.
-    row = jnp.where(idx_ref[r] < n_rows_in, row, 0.0)
+def _fused_tail_kernel(idx_ref, x_ref, basis_ref, *rest, n_rows_in, S, bins,
+                       F, Fv, kp, alpha, gain_floor, noise_frames, hpf,
+                       block_rows):
+    if hpf:
+        mc_ref, mp_ref, o_ref, pow_ref, sig_ref = rest
+    else:
+        o_ref, pow_ref = rest
+        sig_ref = x_ref
+    valid = idx_ref[pl.program_id(0)] < n_rows_in
 
-    n_tiles, S_pad, F, Fv = tail_geometry(S, window, hop)
-    row = jnp.concatenate([row, jnp.zeros((S_pad - S,), jnp.float32)])
+    @pl.when(jnp.logical_not(valid))
+    def _pad_slot():
+        o_ref[...] = jnp.zeros(o_ref.shape, jnp.float32)
 
-    # framing: per tile the even/odd contiguous reshapes of
-    # stft_dft._stft_kernel (the boundary tail is the next span's head)
-    tile_span = FRAME_TILE * hop
-    half = FRAME_TILE // 2
-    frames = []
-    for t in range(n_tiles):
-        span = row[t * tile_span:(t + 1) * tile_span + (window - hop)]
-        even = span[:half * window].reshape(half, window)
-        odd = span[hop:hop + half * window].reshape(half, window)
-        frames.append(jnp.stack([even, odd], axis=1)
-                      .reshape(FRAME_TILE, window))
-    frames = jnp.concatenate(frames)                       # (F, window)
+    @pl.when(valid)
+    def _survivor():
+        n_rows = F + 8
+        if hpf:
+            # causal stride-1 FIR in banded-matmul form; positions past S
+            # are zeroed, as the staged tail zero-pads the FILTERED row
+            for a in range(0, n_rows, block_rows):
+                m = min(block_rows, n_rows - a)
+                cur = x_ref[a:a + m, :]
+                first = (jnp.zeros((1, LANES), jnp.float32) if a == 0
+                         else x_ref[a - 1:a, :])
+                y = (jnp.dot(cur, mc_ref[...], precision=HIGHEST,
+                             preferred_element_type=jnp.float32)
+                     + jnp.dot(prev_rows(cur, first), mp_ref[...],
+                               precision=HIGHEST,
+                               preferred_element_type=jnp.float32))
+                pos = ((a + jax.lax.broadcasted_iota(jnp.int32, y.shape, 0))
+                       * LANES
+                       + jax.lax.broadcasted_iota(jnp.int32, y.shape, 1))
+                sig_ref[a:a + m, :] = jnp.where(pos < S, y, 0.0)
 
-    # windowed DFT as matmul, frame_block tiles per MXU dispatch
-    m = frame_block * FRAME_TILE
-    packed = jnp.concatenate(
-        [jnp.dot(frames[a:a + m], basis_ref[...],
-                 preferred_element_type=jnp.float32)
-         for a in range(0, F, m)])                         # (F, PAD_OUT)
+        # framing + windowed DFT: frame f is rows f and f + 1
+        basis = basis_ref[...]
+        hop = LANES
+        for a in range(0, F, block_rows):
+            m = min(block_rows, F - a)
+            blk = sig_ref[a:a + m + 8, :]
+            nxt = pltpu.roll(blk, m + 7, 0)[:m]
+            packed = (jnp.dot(blk[:m], basis[:hop], precision=HIGHEST,
+                              preferred_element_type=jnp.float32)
+                      + jnp.dot(nxt, basis[hop:], precision=HIGHEST,
+                                preferred_element_type=jnp.float32))
+            o_ref[a:a + m, :] = packed
+            re, im = packed[:, :kp], packed[:, kp:]
+            pow_ref[a:a + m, :] = re * re + im * im
 
-    re, im = packed[:, :bins], packed[:, bins:2 * bins]
-    power = re * re + im * im                              # (F, bins)
-    nf = min(noise_frames, Fv)
-    noise = jnp.mean(power[:nf], axis=0)                   # (bins,)
+        # noise PSD: mean power of the first frames; pad lanes get 1.0
+        nf = min(noise_frames, Fv)
+        head = pow_ref[0:-(-nf // 8) * 8, :]
+        row = jax.lax.broadcasted_iota(jnp.int32, head.shape, 0)
+        noise = jnp.sum(jnp.where(row < nf, head, 0.0), axis=0,
+                        keepdims=True) / nf
+        lane = jax.lax.broadcasted_iota(jnp.int32, noise.shape, 1)
+        noise = jnp.where(lane < bins, noise, 1.0)
 
-    # decision-directed MMSE-STSA recurrence, bin_tile lanes per scan —
-    # the identical per-frame arithmetic of mmse_stsa._mmse_kernel; bins
-    # are padded to the lane tile (pad noise 1.0, as mmse_stsa.ops does)
-    KP = -(-bins // bin_tile) * bin_tile
-    powp = jnp.concatenate([power, jnp.zeros((F, KP - bins))], axis=1)
-    noisep = jnp.concatenate([noise, jnp.ones((KP - bins,))])
-    gains = []
-    for c in range(0, KP, bin_tile):
-        lam = jnp.maximum(noisep[c:c + bin_tile], 1e-10)
-        inv_lam = 1.0 / lam
+        # decision-directed recurrence over the valid frames, the gain
+        # applied in place to both halves of the packed spectrum row
+        inv_lam = 1.0 / jnp.maximum(noise, 1e-10)
 
-        def step(a2_prev, p_t):
-            gamma = jnp.clip(p_t * inv_lam, 1e-8, GAMMA_MAX)
-            xi = alpha * a2_prev \
-                + (1.0 - alpha) * jnp.maximum(gamma - 1.0, 0.0)
-            xi = jnp.maximum(xi, XI_MIN)
-            v = jnp.maximum(xi * gamma / (1.0 + xi), 1e-8)
-            gg = (SQRTPI_2 * jnp.sqrt(v) / gamma
-                  * ((1.0 + v) * i0e_poly(v / 2.0)
-                     + v * i1e_poly(v / 2.0)))
-            gg = jnp.clip(gg, 0.0, 10.0)
-            return (gg * gg) * gamma, jnp.maximum(gg, gain_floor)
+        def step(t, a2_prev):
+            g, a2 = frame_gain(pow_ref[pl.ds(t, 1), :], inv_lam, a2_prev,
+                               alpha)
+            g = jnp.maximum(g, gain_floor)
+            o_ref[pl.ds(t, 1), :] = (o_ref[pl.ds(t, 1), :]
+                                     * jnp.concatenate([g, g], axis=1))
+            return a2
 
-        _, gc = jax.lax.scan(step, jnp.ones((bin_tile,), jnp.float32),
-                             powp[:, c:c + bin_tile])
-        gains.append(gc)
-    gain = jnp.concatenate(gains, axis=1)[:, :bins]        # (F, bins)
-
-    o_ref[0] = jnp.concatenate(
-        [re * gain, im * gain, jnp.zeros((F, PAD_OUT - 2 * bins))], axis=1)
+        jax.lax.fori_loop(0, Fv, step, jnp.ones((1, kp), jnp.float32))
 
 
 def fused_tail_pallas(wave, idx, cfg, hpf=False, frame_block=2,
-                      bin_tile=128, interpret=False):
+                      interpret=False, vmem_limit_bytes=None):
     """wave: (B, S) f32 pre-denoise batch; idx: (R,) padded int32 survivor
-    indices. Returns the packed gain-filtered spectrum (R, F, PAD_OUT) —
+    indices. Returns the packed gain-filtered spectrum (R, F, 2 * KP) —
     feed to `finish` for the overlap-add resynthesis."""
     B, S = wave.shape
     R = idx.shape[0]
     window, hop = cfg.stft_window, cfg.stft_hop
-    assert hop * 2 == window, "kernel exploits 50% overlap"
+    if hop * 2 != window or hop != LANES:
+        raise ValueError("the fused tail needs 50% overlap and a "
+                         f"{LANES}-sample hop (window={window}, hop={hop})")
     bins = window // 2 + 1
-    _, _, F, _ = tail_geometry(S, window, hop)
-    taps = highpass_taps(cfg.hpf_cutoff_hz, cfg.target_rate_hz,
-                         cfg.hpf_taps) if hpf else None
+    _, _, F, Fv = tail_geometry(S, window, hop)
+    kp = spectrum_lanes(bins)
+    n_rows = F + 8
+    xr = jnp.pad(wave.astype(jnp.float32),
+                 ((0, 0), (0, n_rows * hop - S))).reshape(B, n_rows, hop)
     kernel = functools.partial(
-        _fused_tail_kernel, n_rows_in=B, S=S, window=window, hop=hop,
-        bins=bins, alpha=cfg.mmse_alpha, gain_floor=cfg.mmse_gain_floor,
-        noise_frames=cfg.noise_est_frames, taps=taps,
-        frame_block=int(frame_block), bin_tile=int(bin_tile))
+        _fused_tail_kernel, n_rows_in=B, S=S, bins=bins, F=F, Fv=Fv, kp=kp,
+        alpha=cfg.mmse_alpha, gain_floor=cfg.mmse_gain_floor,
+        noise_frames=cfg.noise_est_frames, hpf=hpf,
+        block_rows=int(frame_block) * FRAME_TILE)
+    fixed = lambda r, idx_ref: (0, 0)
+    in_specs = [
+        # the gather: this step's survivor row, clamped for the DMA
+        # (pad slots write zeros without reading it)
+        pl.BlockSpec((None, n_rows, hop),
+                     lambda r, idx_ref: (jnp.minimum(idx_ref[r], B - 1),
+                                         0, 0)),
+        pl.BlockSpec((window, 2 * kp), fixed),
+    ]
+    args = [xr, split_basis(window, kp)]
+    scratch = [pltpu.VMEM((F, kp), jnp.float32)]
+    if hpf:
+        mc, mp = toeplitz_taps(highpass_taps(
+            cfg.hpf_cutoff_hz, cfg.target_rate_hz, cfg.hpf_taps), 1)
+        in_specs += [pl.BlockSpec((LANES, LANES), fixed)] * 2
+        args += [mc, mp]
+        scratch.append(pltpu.VMEM((n_rows, hop), jnp.float32))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(R,),
-        in_specs=[
-            # the gather: this step's survivor row, clamped for the DMA
-            # (the kernel zero-masks pad rows, matching the fill gather)
-            pl.BlockSpec((1, S),
-                         lambda r, idx_ref: (jnp.minimum(idx_ref[r], B - 1),
-                                             0)),
-            pl.BlockSpec((window, PAD_OUT), lambda r, idx_ref: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, F, PAD_OUT), lambda r, idx_ref: (r, 0, 0)),
+        in_specs=in_specs,
+        out_specs=pl.BlockSpec((None, F, 2 * kp),
+                               lambda r, idx_ref: (r, 0, 0)),
+        scratch_shapes=scratch,
     )
     return pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((R, F, PAD_OUT), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((R, F, 2 * kp), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
-    )(idx.astype(jnp.int32), wave.astype(jnp.float32),
-      dft_basis(window, jnp.float32))
+        name="fused_tail",
+    )(idx.astype(jnp.int32), *args)
 
 
 def finish(packed, S, cfg):
     """Inverse-DFT overlap-add resynthesis of the kernel's packed filtered
     spectrum: complexify, slice the valid frames, irfft-OLA — the same
-    istft_ref every staged non-matmul mode runs, so fused == staged
-    bitwise."""
+    istft_ref every staged non-matmul mode runs."""
     bins = cfg.stft_window // 2 + 1
+    kp = packed.shape[-1] // 2
     Fv = (S - cfg.stft_window) // cfg.stft_hop + 1
-    z = jax.lax.complex(packed[..., :bins], packed[..., bins:2 * bins])
+    z = jax.lax.complex(packed[..., :bins], packed[..., kp:kp + bins])
     return istft_ref(z[:, :Fv], S, cfg.stft_window, cfg.stft_hop)

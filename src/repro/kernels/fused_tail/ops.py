@@ -8,18 +8,19 @@ kernels/backend.py exactly as the staged per-stage ops do —
     pallas / interpret ->  kernel.fused_tail_pallas + kernel.finish
     auto-on-TPU        ->  compiled kernel.fused_tail_pallas
 
-The kernel path takes a `TailConfig` (frame_block x bin_tile) chosen by
-the autotuner: `autotune` enumerates CANDIDATES, drops any whose additive
-f32 VMEM footprint model (`vmem_bytes`) exceeds the per-core budget,
-times the survivors (min-of-reps, block_until_ready) and caches the
-winner per (backend mode, survivor bucket, S, hpf). `best_config` is the
-hot-path accessor: tuned entry if present, else the first feasible
-candidate — it never probes, so plans can call it inside a jit trace
-without timing side effects.
+The kernel path takes a `TailConfig` (frame_block) chosen by the
+autotuner: `autotune` enumerates CANDIDATES, drops any whose scoped-VMEM
+model (`vmem_bytes`) exceeds the per-core budget, times the survivors
+(min-of-reps, block_until_ready) and caches the winner per (backend mode,
+survivor bucket, S, hpf). `best_config` is the hot-path accessor: tuned
+entry if present, else the first feasible candidate — it never probes, so
+plans can call it inside a jit trace without timing side effects. The
+kernel is compiled with the budget as its scoped-VMEM limit, so a
+candidate the model admits is one the compiler accepts
+(tests/test_tpu_compile.py compiles each at the model's own figure).
 
-Every knob is a pure perf knob: frame_block only re-tiles the DFT dot's
-M dimension and bin_tile only re-chunks elementwise lanes, both of which
-are bitwise-stable — so the tuner can never change results, only speed.
+frame_block only re-tiles the DFT dot's M dimension, a perf knob: rows of
+a matmul do not depend on how the rows are tiled.
 """
 from __future__ import annotations
 
@@ -33,7 +34,6 @@ import jax.numpy as jnp
 from repro.kernels import backend
 from repro.kernels.fused_tail import kernel as K
 from repro.kernels.fused_tail import ref as R
-from repro.kernels.stft_dft.kernel import PAD_OUT
 
 VMEM_BUDGET = int(os.environ.get("REPRO_FUSED_VMEM_BYTES", 16 * 2 ** 20))
 
@@ -41,39 +41,44 @@ VMEM_BUDGET = int(os.environ.get("REPRO_FUSED_VMEM_BYTES", 16 * 2 ** 20))
 @dataclasses.dataclass(frozen=True)
 class TailConfig:
     frame_block: int = 2   # FRAME_TILE-tiles of frames per DFT dispatch
-    bin_tile: int = 128    # spectral lanes per MMSE scan
 
 
-CANDIDATES = tuple(TailConfig(fb, bt)
-                   for fb in (1, 2, 4, 8) for bt in (128, 256))
+CANDIDATES = tuple(TailConfig(fb) for fb in (1, 2, 4, 8))
+
+# Working set of one frame block, per frame row: the compiler's own
+# scoped-VMEM reports (RESOURCE_EXHAUSTED "Scoped allocation with size
+# ..." at a lowered vmem_limit_bytes) for every candidate, with and
+# without HPF, at S = 16,640 / 110,250 on a described v5e, minus the
+# pipeline buffers and scratch below, peak at 10,012 bytes per row
+# (frame_block 1, S = 16,640). Five (rows, 2 * KP) f32 values covers it.
+WORK_ROW_VALUES = 5
+# Mosaic's own internal scratch, above the buffers the model names.
+INTERNAL_BYTES = 512 * 2 ** 10
 
 
-def vmem_bytes(tc: TailConfig, S, window=256, hop=128, hpf=False,
-               hpf_taps=129) -> int:
-    """Additive f32 model of the kernel's per-grid-step VMEM residency."""
-    _, S_pad, F, _ = K.tail_geometry(S, window, hop)
-    bins = window // 2 + 1
-    KP = -(-bins // tc.bin_tile) * tc.bin_tile
-    n = S_pad                      # zero-padded row
+def vmem_bytes(tc: TailConfig, S, window=256, hop=128, hpf=False) -> int:
+    """Scoped VMEM one grid step needs: the double-buffered in/out blocks
+    and the scratch the kernel declares (all (8, 128)-tile aligned
+    already), plus a per-frame-row working set fit to the compiler."""
+    _, _, F, _ = K.tail_geometry(S, window, hop)
+    kp = K.spectrum_lanes(window // 2 + 1)
+    rows = F + 8                               # the (rows, 128) signal view
+    n = 2 * rows * hop                         # survivor row block
+    n += 2 * window * 2 * kp                   # DFT basis block
+    n += 2 * F * 2 * kp                        # packed spectrum block
+    n += F * kp                                # power scratch
     if hpf:
-        n_ft = -(-S // K.FIR_TILE)
-        n += S + hpf_taps - 1                 # causal-padded input
-        n += n_ft * (K.FIR_TILE + hpf_taps - 1)  # stacked FIR spans
-        n += n_ft * K.FIR_TILE                # materialised scan output
-    n += F * window                # frames
-    n += window * PAD_OUT          # basis
-    n += tc.frame_block * 128 * PAD_OUT  # dot chunk in flight
-    n += F * PAD_OUT               # packed output block
-    n += F * (bins + KP)           # power + lane-padded power
-    n += F * KP + 2 * KP           # gains + lam/inv_lam
-    return 4 * n
+        n += 2 * 2 * K.LANES * K.LANES         # banded tap matrices
+        n += rows * hop                        # filtered-row scratch
+    block_rows = min(tc.frame_block * K.FRAME_TILE, F)
+    n += WORK_ROW_VALUES * block_rows * 2 * kp
+    return 4 * n + INTERNAL_BYTES
 
 
-def feasible(S, window=256, hop=128, hpf=False, hpf_taps=129,
-             budget=None):
+def feasible(S, window=256, hop=128, hpf=False, budget=None):
     budget = VMEM_BUDGET if budget is None else budget
     return [tc for tc in CANDIDATES
-            if vmem_bytes(tc, S, window, hop, hpf, hpf_taps) <= budget]
+            if vmem_bytes(tc, S, window, hop, hpf) <= budget]
 
 
 # (backend mode, rows, S, hpf) -> TailConfig
@@ -92,7 +97,7 @@ def best_config(rows, S, cfg, hpf=False) -> TailConfig:
     tuned = _TUNED.get(_key(rows, S, hpf))
     if tuned is not None:
         return tuned
-    feas = feasible(S, cfg.stft_window, cfg.stft_hop, hpf, cfg.hpf_taps)
+    feas = feasible(S, cfg.stft_window, cfg.stft_hop, hpf)
     if not feas:
         raise ValueError(
             f"no VMEM-feasible fused-tail config for S={S} "
@@ -109,16 +114,14 @@ def autotune(wave, idx, cfg, hpf=False, reps=2) -> TailConfig:
     if key in _TUNED:
         return _TUNED[key]
     use_pallas, interpret = backend.resolve()
-    feas = feasible(S, cfg.stft_window, cfg.stft_hop, hpf, cfg.hpf_taps)
+    feas = feasible(S, cfg.stft_window, cfg.stft_hop, hpf)
     if not feas:
         raise ValueError(f"no VMEM-feasible fused-tail config for S={S}")
     records = []
     for tc in feas:
         if use_pallas:
-            fn = jax.jit(lambda w, i, tc=tc: K.finish(
-                K.fused_tail_pallas(w, i, cfg, hpf, tc.frame_block,
-                                    tc.bin_tile, interpret=interpret),
-                w.shape[1], cfg))
+            fn = jax.jit(lambda w, i, tc=tc: _kernel_path(
+                w, i, cfg, hpf, tc, interpret))
         else:
             # ref path ignores tiling; probe once so records stay uniform
             fn = jax.jit(lambda w, i: R.fused_tail_ref(w, i, cfg, hpf))
@@ -151,6 +154,11 @@ def fused_tail(wave, idx, cfg, hpf=False, tile: TailConfig | None = None):
     if not use_pallas:
         return R.fused_tail_ref(wave, idx, cfg, hpf)
     tc = tile or best_config(idx.shape[0], wave.shape[1], cfg, hpf)
+    return _kernel_path(wave, idx, cfg, hpf, tc, interpret)
+
+
+def _kernel_path(wave, idx, cfg, hpf, tc, interpret):
     packed = K.fused_tail_pallas(wave, idx, cfg, hpf, tc.frame_block,
-                                 tc.bin_tile, interpret=interpret)
+                                 interpret=interpret,
+                                 vmem_limit_bytes=VMEM_BUDGET)
     return K.finish(packed, wave.shape[1], cfg)

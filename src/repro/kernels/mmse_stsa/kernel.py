@@ -10,9 +10,13 @@ Design for TPU:
     via Abramowitz-Stegun 9.8.1-9.8.8 polynomials — no table lookups, no
     overflow for large v (loud signal bins).
 
+Every value in the kernel is 2-D: a frame is a (1, 128) row read and
+written with a dynamic one-row slice, and the noise PSD is carried as
+(B, 1, K) so its block (1, 128) spans the whole sublane dim.
+
 VMEM per grid step (F frames, 128-bin tile, f32):
-  power block (1, F, 128) + gain block (1, F, 128)  ~ F=896: 2 x 448 KiB
-  noise block (1, 128)                               ~ 0.5 KiB
+  power block (F, 128) + gain block (F, 128)  ~ F=896: 2 x 448 KiB
+  noise block (1, 128)                         ~ 4 KiB (one sublane tile)
 """
 import functools
 
@@ -59,25 +63,32 @@ def i1e_poly(x):
     return jnp.where(x <= 3.75, small, large)
 
 
+def frame_gain(p, inv_lam, a2_prev, alpha):
+    """One decision-directed MMSE-STSA frame: power row p, 1/noise, and the
+    previous frame's A^2/lambda -> (unfloored gain, A^2/lambda carry)."""
+    gamma = jnp.clip(p * inv_lam, 1e-8, GAMMA_MAX)
+    xi = alpha * a2_prev + (1.0 - alpha) * jnp.maximum(gamma - 1.0, 0.0)
+    xi = jnp.maximum(xi, XI_MIN)
+    v = jnp.maximum(xi * gamma / (1.0 + xi), 1e-8)
+    g = (SQRTPI_2 * jnp.sqrt(v) / gamma
+         * ((1.0 + v) * i0e_poly(v / 2.0) + v * i1e_poly(v / 2.0)))
+    g = jnp.clip(g, 0.0, 10.0)
+    return g, (g * g) * gamma
+
+
 def _mmse_kernel(power_ref, noise_ref, gain_ref, *, alpha, gain_floor,
                  n_frames):
-    lam = jnp.maximum(noise_ref[0], 1e-10)           # (BIN_TILE,)
+    lam = jnp.maximum(noise_ref[...], 1e-10)         # (1, BIN_TILE)
     inv_lam = 1.0 / lam
 
     def frame_step(t, a2_prev):
-        p = power_ref[0, t]                           # (BIN_TILE,)
-        gamma = jnp.clip(p * inv_lam, 1e-8, GAMMA_MAX)
-        xi = alpha * a2_prev + (1.0 - alpha) * jnp.maximum(gamma - 1.0, 0.0)
-        xi = jnp.maximum(xi, XI_MIN)
-        v = jnp.maximum(xi * gamma / (1.0 + xi), 1e-8)
-        g = (SQRTPI_2 * jnp.sqrt(v) / gamma
-             * ((1.0 + v) * i0e_poly(v / 2.0) + v * i1e_poly(v / 2.0)))
-        g = jnp.clip(g, 0.0, 10.0)
-        gain_ref[0, t] = jnp.maximum(g, gain_floor)
-        return (g * g) * gamma                        # A^2/lambda carry
+        p = power_ref[pl.ds(t, 1), :]                 # (1, BIN_TILE)
+        g, a2 = frame_gain(p, inv_lam, a2_prev, alpha)
+        gain_ref[pl.ds(t, 1), :] = jnp.maximum(g, gain_floor)
+        return a2
 
     jax.lax.fori_loop(0, n_frames, frame_step,
-                      jnp.ones((BIN_TILE,), jnp.float32))
+                      jnp.ones((1, BIN_TILE), jnp.float32))
 
 
 def mmse_gain_pallas(power, noise_psd, alpha=0.98, gain_floor=0.1,
@@ -92,10 +103,12 @@ def mmse_gain_pallas(power, noise_psd, alpha=0.98, gain_floor=0.1,
         kernel,
         grid=(B, K // BIN_TILE),
         in_specs=[
-            pl.BlockSpec((1, F, BIN_TILE), lambda b, k: (b, 0, k)),
-            pl.BlockSpec((1, BIN_TILE), lambda b, k: (b, k)),
+            pl.BlockSpec((None, F, BIN_TILE), lambda b, k: (b, 0, k)),
+            pl.BlockSpec((None, 1, BIN_TILE), lambda b, k: (b, 0, k)),
         ],
-        out_specs=pl.BlockSpec((1, F, BIN_TILE), lambda b, k: (b, 0, k)),
+        out_specs=pl.BlockSpec((None, F, BIN_TILE), lambda b, k: (b, 0, k)),
         out_shape=jax.ShapeDtypeStruct((B, F, K), jnp.float32),
         interpret=interpret,
-    )(power.astype(jnp.float32), noise_psd.astype(jnp.float32))
+        name="mmse_stsa",
+    )(power.astype(jnp.float32),
+      noise_psd.astype(jnp.float32).reshape(B, 1, K))

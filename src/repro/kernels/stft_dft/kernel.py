@@ -4,39 +4,42 @@ TPU adaptation of the paper's FFT stage (Apache Commons radix FFT on CPU):
 a 256-point real DFT is a (frames x 256) @ (256 x 2*bins) matmul — MXU-native,
 no butterfly/bit-reversal (which would serialize on a systolic array).
 
-Framing exploits the 50% overlap: within a tile's contiguous sample span, the
-even frames are one contiguous reshape and the odd frames a hop-shifted
-reshape — no gathers inside the kernel. Because Pallas blocked indexing cannot
-express *overlapping* blocks, each grid step receives its (FRAME_TILE*hop)
-main span plus a (window-hop) boundary tail (precomputed view, ops.py).
+Layout: with 50% overlap (window = 2 * hop, hop a multiple of 128) the
+signal viewed as rows of `hop` samples makes frame f the concatenation of
+rows f and f + 1, so the windowed DFT is
 
-Grid: (batch, frame_tiles). VMEM per step:
-  main span (1,1,32768) f32  = 128 KiB      (FRAME_TILE=256, hop=128)
-  tail      (1,1,128)        = 0.5 KiB
-  dft basis (256,384)        = 384 KiB      (grid-invariant, stays resident)
-  out       (1,256,384)      = 384 KiB
-MXU alignment: contraction dim 256 and padded output dim 384 are multiples of
-the 128-lane tiling.
+    packed[f] = x[f] @ basis[:hop] + x[f + 1] @ basis[hop:]
+
+— two matmuls over the same row tile, no gathers and no in-kernel reshape.
+Row f + 1 comes from a sublane roll of the tile; the tile's last frame
+takes its second half from the first row of an aligned 8-row halo block
+that starts just after the tile.
+
+Grid: (batch, frame_tiles). VMEM per step (FRAME_TILE=128, hop=128, f32):
+  rows (128,128) 64 KiB + halo (8,128) 4 KiB + basis (256,384) 384 KiB
+  + out (128,384) 192 KiB, double-buffered by the pipeline.
+MXU alignment: contraction dim 128 and padded output dim 384 are multiples
+of the 128-lane tiling.
 """
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.stft_dft.ref import hamming
 
 FRAME_TILE = 128   # block-shape hillclimb: 256 -> 128 cuts pad waste ~4.5%
                    # and halves the per-step VMEM footprint (EXPERIMENTS §Perf)
 PAD_OUT = 384          # 2*(128+1) = 258 -> padded to 3*128
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def dft_basis(window=256, dtype=jnp.float32, windowed=True):
-    """Packed real-DFT basis (window, PAD_OUT): [cos | -sin | zero-pad].
-
-    With windowed=True the Hamming window is folded into the basis rows
-    (diag(w) @ basis), fusing the windowing into the DFT matmul."""
+def dft_basis_np(window=256, windowed=True):
+    """Packed real-DFT basis (window, PAD_OUT) as numpy: [cos | -sin |
+    zero-pad]. With windowed=True the Hamming window is folded into the
+    basis rows (diag(w) @ basis), fusing the windowing into the DFT
+    matmul."""
     bins = window // 2 + 1
     n = np.arange(window)[:, None]
     k = np.arange(bins)[None, :]
@@ -46,50 +49,68 @@ def dft_basis(window=256, dtype=jnp.float32, windowed=True):
     basis[:, bins:2 * bins] = -np.sin(ang)
     if windowed:
         basis *= hamming(window)[:, None]
-    return jnp.asarray(basis, dtype)
+    return basis
 
 
-def _stft_kernel(x_ref, tail_ref, basis_ref, o_ref, *, window, hop,
-                 frame_tile):
-    span = jnp.concatenate([x_ref[0, 0], tail_ref[0, 0]])   # (T*hop + w-hop,)
-    half = frame_tile // 2
-    even = span[:half * window].reshape(half, window)
-    odd = span[hop:hop + half * window].reshape(half, window)
-    frames = jnp.stack([even, odd], axis=1).reshape(frame_tile, window)
-    o_ref[0] = jnp.dot(frames, basis_ref[...],
-                       preferred_element_type=jnp.float32)
+def dft_basis(window=256, dtype=jnp.float32, windowed=True):
+    """`dft_basis_np` as a device constant of `dtype`."""
+    return jnp.asarray(dft_basis_np(window, windowed), dtype)
+
+
+def next_rows(cur, last_next):
+    """Row i of the result is cur[i + 1]; the last row is `last_next`."""
+    n = cur.shape[0]
+    rolled = pltpu.roll(cur, n - 1, 0)
+    row = jax.lax.broadcasted_iota(jnp.int32, cur.shape, 0)
+    return jnp.where(row == n - 1, last_next, rolled)
+
+
+def windowed_dft(cur, nxt, basis):
+    """Frames (cur | nxt) @ basis with the 50%-overlap halves split."""
+    hop = cur.shape[1]
+    return (jnp.dot(cur, basis[:hop], precision=HIGHEST,
+                    preferred_element_type=jnp.float32)
+            + jnp.dot(nxt, basis[hop:], precision=HIGHEST,
+                      preferred_element_type=jnp.float32))
+
+
+def _stft_kernel(x_ref, halo_ref, basis_ref, o_ref):
+    cur = x_ref[...]                                      # (FT, hop)
+    nxt = next_rows(cur, halo_ref[0:1, :])
+    o_ref[...] = windowed_dft(cur, nxt, basis_ref[...])
 
 
 def stft_pallas(x, window=256, hop=128, interpret=False):
     """x: (B, S) f32, S = n_tiles*FRAME_TILE*hop + (window-hop)
     -> (B, F, PAD_OUT) packed [re | im | pad], F = n_tiles*FRAME_TILE."""
-    assert hop * 2 == window, "kernel exploits 50% overlap"
+    if hop * 2 != window or hop % 128:
+        raise ValueError("the kernel needs 50% overlap and a hop that is a "
+                         f"multiple of 128 (window={window}, hop={hop})")
     B, S = x.shape
     tile_span = FRAME_TILE * hop
     tail_len = window - hop
-    assert (S - tail_len) % tile_span == 0, (
-        f"S={S} must be n*{tile_span}+{tail_len} (ops.py pads)")
+    if (S - tail_len) % tile_span:
+        raise ValueError(f"S={S} must be n*{tile_span}+{tail_len} "
+                         "(ops.pad_for_stft pads)")
     n_tiles = (S - tail_len) // tile_span
     F = n_tiles * FRAME_TILE
-    main = x[:, :n_tiles * tile_span].reshape(B, n_tiles, tile_span)
-    tail_idx = (np.arange(n_tiles)[:, None] * tile_span + tile_span
-                + np.arange(tail_len)[None, :])
-    tails = x[:, tail_idx.reshape(-1)].reshape(B, n_tiles, tail_len)
-    basis = dft_basis(window, jnp.float32)
-
-    kernel = functools.partial(_stft_kernel, window=window, hop=hop,
-                               frame_tile=FRAME_TILE)
-    out = pl.pallas_call(
-        kernel,
+    # rows of hop samples, padded to whole 8-row halo blocks past the end
+    n_rows = F + 8
+    xr = jnp.pad(x.astype(jnp.float32),
+                 ((0, 0), (0, n_rows * hop - S))).reshape(B, n_rows, hop)
+    blocks = FRAME_TILE // 8
+    return pl.pallas_call(
+        _stft_kernel,
         grid=(B, n_tiles),
         in_specs=[
-            pl.BlockSpec((1, 1, tile_span), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, 1, tail_len), lambda b, t: (b, t, 0)),
+            pl.BlockSpec((None, FRAME_TILE, hop), lambda b, t: (b, t, 0)),
+            pl.BlockSpec((None, 8, hop),
+                         lambda b, t: (b, (t + 1) * blocks, 0)),
             pl.BlockSpec((window, PAD_OUT), lambda b, t: (0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, FRAME_TILE, PAD_OUT),
+        out_specs=pl.BlockSpec((None, FRAME_TILE, PAD_OUT),
                                lambda b, t: (b, t, 0)),
         out_shape=jax.ShapeDtypeStruct((B, F, PAD_OUT), jnp.float32),
         interpret=interpret,
-    )(main.astype(jnp.float32), tails.astype(jnp.float32), basis)
-    return out
+        name="stft_dft",
+    )(xr, xr, dft_basis(window, jnp.float32))

@@ -6,16 +6,10 @@ dry-run sees its 512 placeholder devices)."""
 from __future__ import annotations
 
 import jax
-
-try:                                    # jax >= 0.4.35
-    from jax.sharding import AxisType
-except ImportError:                     # older jax: make_mesh has no
-    AxisType = None                     # axis_types parameter
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes):
-    if AxisType is None:
-        return jax.make_mesh(shape, axes)
     return jax.make_mesh(shape, axes,
                          axis_types=(AxisType.Auto,) * len(axes))
 

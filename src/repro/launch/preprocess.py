@@ -48,6 +48,7 @@ from repro.launch.mesh import make_local_mesh
 from repro.obs import metrics as obs_metrics
 from repro.obs import telemetry as obs_telemetry
 from repro.obs import tracing as obs_tracing
+from repro.runtime import enable_compile_cache
 
 _FRAC_KEYS = ("frac_rain", "frac_silence", "frac_kept", "frac_cicada15")
 
@@ -113,6 +114,7 @@ def main(argv=None):
     if args.store_max_bytes is not None and not args.store:
         ap.error("--store-max-bytes requires --store")
 
+    enable_compile_cache()
     cfg = SERF_AUDIO
     n_batches = max(1, int(round(args.minutes / args.batch_long_chunks)))
     mesh = make_local_mesh()

@@ -189,6 +189,8 @@ def main(argv=None):
     args = ap.parse_args(argv)
     if (args.telemetry or args.trace) and not args.audio:
         ap.error("--telemetry/--trace instrument the audio serving tier")
+    from repro.runtime import enable_compile_cache
+    enable_compile_cache()
     return _audio_main(args) if args.audio else _lm_main(args)
 
 

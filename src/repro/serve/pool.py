@@ -42,6 +42,7 @@ from repro.data.queue import StandingWorkQueue
 from repro.dist.data_plane import StoreDataPlane
 from repro.dist.service import QueueService, unpack_result
 from repro.dist.transport import InProcTransport, ProcTransport, TcpTransport
+from repro.runtime import refuse_worker_processes
 from repro.dist.worker import run_worker
 from repro.ft.failure import StragglerDetector
 from repro.kernels import backend
@@ -158,6 +159,8 @@ class WorkerPool:
             raise RuntimeError("pool already started")
         self._started = True
         if self.transport in ("proc", "tcp"):
+            refuse_worker_processes(
+                f"WorkerPool(transport={self.transport!r})")
             self._tp = TcpTransport() if self.transport == "tcp" \
                 else ProcTransport()
             self._tp.serve(self.service)

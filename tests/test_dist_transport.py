@@ -198,3 +198,33 @@ def test_transport_parity_bit_identical(shards, tmp_path):
             np.testing.assert_array_equal(a.cleaned, b.cleaned)
             assert a.n_kept == b.n_kept
             assert a.src_bytes == b.src_bytes
+
+
+# ------------------------------------------------ one process per chip
+
+@pytest.mark.parametrize("runtime", ["spawn_worker", "pool_proc",
+                                     "pool_tcp", "sharded_proc"])
+def test_worker_processes_refused_on_tpu(monkeypatch, runtime):
+    """On a TPU backend every path that would start a worker process
+    raises at once, naming the one-process-per-chip rule, and starts
+    nothing."""
+    import jax
+    from repro.dist.transport import ProcTransport
+    from repro.serve import WorkerPool
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="one process per chip"):
+        if runtime == "spawn_worker":
+            tp = ProcTransport()
+            tp.serve(QueueService(WorkQueue(1)))
+            try:
+                tp.spawn_worker()
+            finally:
+                tp.close()
+        elif runtime.startswith("pool"):
+            WorkerPool(cfg, workers=1,
+                       transport=runtime.split("_")[1]).start()
+        else:
+            pre = Preprocessor(cfg, plan="sharded", shards=2,
+                               transport="proc", pad_multiple=1)
+            list(pre.run(_stream(1)))

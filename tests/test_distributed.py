@@ -112,6 +112,36 @@ def test_pipeline_sharded_matches_single_device():
     """)
 
 
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+def test_async_plan_sharded_matches_single_device(mode):
+    """The data-parallel main path on 4 devices — the stage chain and the
+    fused survivor tail under shard_map (`graph.per_chunk`), the tail's
+    batch gathered to every device and its survivor list split — gives
+    the same survivor sets and cleaned audio as one device."""
+    _run_subprocess(f"""
+    from repro.configs import SERF_AUDIO as cfg
+    from repro.core.plans import Preprocessor
+    from repro.data.loader import audio_batch_maker
+    from repro.distributed.sharding import NULL_RULES, ShardingRules
+    from repro.kernels import backend
+    make = audio_batch_maker(seed=25, batch_long_chunks=4)
+    stream = [(w, (make(w)[0], None)) for w in range(2)]
+    rules = ShardingRules(_make_mesh((4, 1), ("data", "model")))
+    with backend.use({mode!r}):
+        sh = list(Preprocessor(cfg, rules, plan="async", depth=2,
+                               pad_multiple=4).run(stream))
+        one = list(Preprocessor(cfg, NULL_RULES, plan="async", depth=2,
+                                pad_multiple=1).run(stream))
+    assert sum(r.n_kept for r in one) > 0
+    for a, b in zip(sh, one, strict=True):
+        np.testing.assert_array_equal(np.asarray(a.det.keep),
+                                      np.asarray(b.det.keep))
+        # same kernels and per-row arithmetic: only XLA's fusion of the
+        # jnp code around them may change with the per-device shape
+        np.testing.assert_allclose(a.cleaned, b.cleaned, rtol=0, atol=1e-5)
+    """)
+
+
 def test_train_step_sharded_matches_single_device():
     """One TP+DP train step == single-device step (tiny f32 model)."""
     _run_subprocess("""

@@ -47,8 +47,6 @@ def test_walker_counts_scan_trip_counts():
     assert abs(fu["dot_flops"] - expected) / expected < 0.05, fu
     # XLA's own counter misses the trip count on the scanned version
     ca = jax.jit(scanned).lower(w, x).compile().cost_analysis()
-    if isinstance(ca, (list, tuple)):   # older jax: one dict per program
-        ca = ca[0]
     assert ca["flops"] < 0.5 * expected
     """)
 
@@ -56,11 +54,8 @@ def test_walker_counts_scan_trip_counts():
 def test_walker_counts_collectives_inside_scan():
     _run("""
     from jax.sharding import PartitionSpec as P, NamedSharding
-    try:                                # AxisType is newer-jax only
-        from jax.sharding import AxisType
-        mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
-    except ImportError:
-        mesh = jax.make_mesh((4,), ("model",))
+    from jax.sharding import AxisType
+    mesh = jax.make_mesh((4,), ("model",), axis_types=(AxisType.Auto,))
     L, E, B = 5, 64, 8
     w = jax.ShapeDtypeStruct((L, E, E), jnp.float32)
     x = jax.ShapeDtypeStruct((B, E), jnp.float32)

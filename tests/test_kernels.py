@@ -114,7 +114,7 @@ def test_fused_tail_kernel_vs_composed_oracle(hpf, n_tiles):
     from repro.configs import SERF_AUDIO as cfg
     from repro.kernels.fused_tail import kernel as FTK, ref as FTR
     rng = np.random.RandomState(10 * n_tiles + hpf)
-    S = n_tiles * FK.OUT_TILE // 16 * 128 + 256
+    S = n_tiles * SK.FRAME_TILE * 128 + 256
     wave = jnp.asarray(rng.randn(5, S).astype(np.float32) * 0.3)
     idx = jnp.asarray([3, 0, 4, 7], jnp.int32)      # one pad slot
     packed = FTK.fused_tail_pallas(wave, idx, cfg, hpf=hpf,
