@@ -96,9 +96,9 @@ def run(minutes=16.0, batch_long_chunks=2, depths=(1, 2, 4, 8), seed=11):
         rows.append([label, wall, n_chunks / wall, retraces,
                      rep.get("overlapped", 0),
                      rep.get("d2h_bytes_per_batch", 0) / 2**20,
-                     rep.get("old_boundary_bytes_per_batch", 0) / 2**20])
+                     rep.get("h2d_bytes_per_batch", 0) / 2**20])
     table(rows, ["config", "wall s", "chunks/s", "tail retraces",
-                 "overlapped", "D2H MB/batch", "old boundary MB/batch"],
+                 "overlapped", "D2H MB/batch", "H2D MB/batch"],
           title=f"Dispatch depth x padding ({n_batches} batches, "
                 f"{batch_long_chunks} long chunks each)")
 
@@ -114,16 +114,10 @@ def run(minutes=16.0, batch_long_chunks=2, depths=(1, 2, 4, 8), seed=11):
             r["tail_retraces"] <= np.ceil(np.log2(
                 batch_long_chunks * 12)) + 1
             for r in recs if r.get("bucket") == "pow2"),
-        # host-boundary economy: mask + idx + padded cleaned vs the old
-        # round-trip MEASURED on this stream (full wave5 + mask down,
-        # survivors up, cleaned down) — not a flat 2x-full-batch model
+        # host-boundary traffic: the batch + idx up, mask + padded
+        # cleaned down
         "boundary_per_batch": a4["d2h_bytes_per_batch"]
         + a4["h2d_bytes_per_batch"],
-        "old_boundary_per_batch": a4["old_boundary_bytes_per_batch"],
-        "full_batch_bytes": a4["full_batch_bytes"],
-        "transfer_drop": 1 - (a4["d2h_bytes_per_batch"]
-                              + a4["h2d_bytes_per_batch"])
-        / a4["old_boundary_bytes_per_batch"],
     }
     path = save_json("BENCH_pipeline", {"rows": recs, "findings": findings})
     print(f"\nasync d={d_head} pow2 vs streaming: {stream_wall:.2f}s -> "
@@ -131,10 +125,7 @@ def run(minutes=16.0, batch_long_chunks=2, depths=(1, 2, 4, 8), seed=11):
           f"({findings['speedup_vs_streaming']:.2f}x, "
           f"{'beats' if findings['async_d4_pow2_beats_streaming'] else 'does NOT beat'}"
           f" the one-ahead baseline); host boundary "
-          f"{findings['boundary_per_batch'] / 2**20:.2f} MB/batch vs the "
-          f"old round-trip's measured "
-          f"{findings['old_boundary_per_batch'] / 2**20:.2f} MB/batch "
-          f"({findings['transfer_drop']:.0%} less)")
+          f"{findings['boundary_per_batch'] / 2**20:.2f} MB/batch")
     print(f"record -> {path}")
     return findings
 

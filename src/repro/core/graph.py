@@ -496,7 +496,8 @@ class PipelineGraph:
 
     def _run(self, stages, state, rules):
         for st in stages:
-            state = st.apply(state, rules)
+            with jax.named_scope(st.name):     # the stage's ops, by name
+                state = st.apply(state, rules)
         return state
 
     def _run_sharded(self, stages, wave, rules):
@@ -582,11 +583,14 @@ class PipelineGraph:
                 "the canonical fused tail; use tail_indexed")
         # on a mesh every device cleans an equal share of the survivor
         # list out of the whole batch (gathered to each device once), so
-        # survivor imbalance between the devices' own chunks costs nothing
-        return per_chunk(
-            rules, lambda w, i: fused_tail_ops.fused_tail(
-                w, i, self.cfg, hpf=spec["hpf"]),
-            wave, idx, replicated=(0,))
+        # survivor imbalance between the devices' own chunks costs nothing.
+        # Scoped `fused_tail`; inside it the kernel path scopes the Pallas
+        # call (`kernel`) apart from the XLA resynthesis (`istft`).
+        with jax.named_scope("fused_tail"):
+            return per_chunk(
+                rules, lambda w, i: fused_tail_ops.fused_tail(
+                    w, i, self.cfg, hpf=spec["hpf"]),
+                wave, idx, replicated=(0,))
 
     def fused(self, audio, rules=NULL_RULES) -> PipelineOutput:
         """Single-trace mode: the whole chain, removed chunks masked but

@@ -143,9 +143,13 @@ are off. What each plan emits and where it lands:
     the per-batch `BatchResult.timings` dict carries (the dict stays —
     it is the per-batch view, the registry is the aggregate).
     `AsyncPlan.last_timings` is now a bounded ring (`TIMINGS_CAP`).
-  * spans (`obs.tracing`, visible in Perfetto): `detect_dispatch`
-    (async window fill), `tail` (mask readback + compaction + tail
-    dispatch), `emit` (blocking cleaned readback), `fused_batch`;
+  * spans (`obs.tracing`: the Chrome trace when a tracer is installed,
+    and `repro.<name>` on the `jax.profiler` trace's host plane while a
+    profiler session records), each carrying the batch's `wid`:
+    `detect_dispatch` (async window fill: `h2d_put`, `detect_enqueue`),
+    `mask_wait`, `compact`, `tail_enqueue`, `emit` (blocking cleaned
+    readback: `tail_wait`, `d2h_wait`), `fused_batch`; the `_s` timings
+    are these spans' durations (`obs_tracing.timed`);
     ShardedPlan's proc master additionally marks `accept` (result
     accepted at the completion gate) and `emit_gated` instants, whose
     gap makes straggler-blocked emission visible. Worker processes
@@ -272,17 +276,28 @@ def _cache_key(kind, graph: PipelineGraph, rules):
 
 def _phase_fn(kind, graph: PipelineGraph, rules):
     """Plain (un-jitted) callable for one phase — what dry-run lowering and
-    the jit cache both consume."""
+    the jit cache both consume. Each is a named function, so its program
+    reads `jit_<phase>` in HLO and in a device trace."""
     if kind == "fused":
-        return lambda a: graph.fused(a, rules)
+        def fused(a):
+            return graph.fused(a, rules)
+        return fused
     if kind == "detect":
-        return lambda a: graph.detection(a, rules)
+        def detect(a):
+            return graph.detection(a, rules)
+        return detect
     if kind in ("tail", "mmse"):
-        return lambda w: graph.tail(w, rules)
+        def tail(w):
+            return graph.tail(w, rules)
+        return tail
     if kind == "tail_idx":
-        return lambda w, i: graph.tail_indexed(w, i, rules)
+        def tail_idx(w, i):
+            return graph.tail_indexed(w, i, rules)
+        return tail_idx
     if kind == "tail_idx_fused":
-        return lambda w, i: graph.tail_indexed_fused(w, i, rules)
+        def tail_idx_fused(w, i):
+            return graph.tail_indexed_fused(w, i, rules)
+        return tail_idx_fused
     raise KeyError(f"unknown phase {kind!r}")
 
 
@@ -320,14 +335,20 @@ class BatchResult:
     labels: object = field(default=None, repr=False)   # loader passthrough
     src_bytes: int = 0              # measured input bytes (throughput acct)
     timings: dict = field(default=None, repr=False)
-    # per-batch pipeline instrumentation (two-phase-family plans):
-    #   dispatch_s  detect enqueue time (async — not detect compute time)
-    #   readback_s  blocking part of the keep-mask readback
-    #   compact_s   host index bookkeeping (the whole "master" role now)
-    #   tail_s      tail enqueue + async cleaned-copy start
-    #   emit_s      blocking part of the cleaned readback at emission
+    # per-batch pipeline instrumentation (two-phase-family plans); each
+    # `_s` key is the duration of the `repro.*` span named beside it:
+    #   dispatch_s  detect_dispatch: host copy + detect enqueue (async —
+    #               not detect compute time)
+    #   readback_s  mask_wait: blocking part of the keep-mask readback
+    #   compact_s   compact: host index bookkeeping (the "master" role)
+    #   tail_s      tail_enqueue: tail enqueue + async cleaned-copy start
+    #   emit_s      emit: blocking part of the cleaned readback, of it
+    #     tail_wait_s  tail_wait: until the tail's output exists on device
+    #     d2h_wait_s   d2h_wait: the rest of the copy back and its host
+    #                  re-layout (both only for batches that ran a tail)
     #   in_flight   detect batches in the window when this one dispatched
     #   d2h_bytes / h2d_bytes   host-boundary traffic this batch caused
+    #               (h2d counts the batch itself when it came from host)
     #   tail_rows / n_real      padded tail batch rows vs real survivors
 
 
@@ -337,7 +358,8 @@ class BatchResult:
 # ring.
 TIMINGS_CAP = 4096
 
-_STAGE_KEYS = ("dispatch_s", "readback_s", "compact_s", "tail_s", "emit_s")
+_STAGE_KEYS = ("dispatch_s", "readback_s", "compact_s", "tail_s", "emit_s",
+               "tail_wait_s", "d2h_wait_s")
 
 
 def _record_batch(plan_name, res: "BatchResult"):
@@ -495,63 +517,48 @@ class TwoPhasePlan(ExecutionPlan):
         pre-denoise waveform never crosses the host boundary. With
         `donate` the wave5 buffer is donated to the tail gather, so the
         det record's wave5 must not be read after this call."""
-        with obs_tracing.span("tail", wid=wid):
-            return self._start_tail_inner(det, wid, extra, src_bytes,
-                                          timings)
-
-    def _start_tail_inner(self, det, wid, extra, src_bytes, timings):
-        t0 = time.perf_counter()
-        keep = np.asarray(det.keep)                   # the only readback
-        t1 = time.perf_counter()
-        idx, n_real = SCHED.survivor_indices(keep, self.pad_multiple,
-                                             self.bucket)
-        t2 = time.perf_counter()
-        out, h2d = None, 0
-        if n_real:
-            donate = (0,) if self.donate else ()
-            kind = "tail_idx_fused" if self.fuse_tail else "tail_idx"
-            tail = _jitted(kind, self.graph, self.rules, donate,
-                           shape=len(idx))
-            out = tail(det.wave5, jnp.asarray(idx))   # async dispatch
-            if hasattr(out, "copy_to_host_async"):
-                out.copy_to_host_async()              # stream back early
-            h2d = idx.nbytes
-        t3 = time.perf_counter()
         timings = dict(timings or {})
+        with obs_tracing.timed("mask_wait", timings, "readback_s", wid=wid):
+            keep = np.asarray(det.keep)               # the only readback
+        with obs_tracing.timed("compact", timings, "compact_s", wid=wid):
+            idx, n_real = SCHED.survivor_indices(keep, self.pad_multiple,
+                                                 self.bucket)
+        out = None
+        with obs_tracing.timed("tail_enqueue", timings, "tail_s", wid=wid):
+            if n_real:
+                donate = (0,) if self.donate else ()
+                kind = "tail_idx_fused" if self.fuse_tail else "tail_idx"
+                tail = _jitted(kind, self.graph, self.rules, donate,
+                               shape=len(idx))
+                out = tail(det.wave5, jnp.asarray(idx))   # async dispatch
+                if hasattr(out, "copy_to_host_async"):
+                    out.copy_to_host_async()          # stream back early
         timings.update(
-            readback_s=t1 - t0, compact_s=t2 - t1, tail_s=t3 - t2,
-            h2d_bytes=h2d, d2h_bytes=keep.nbytes,
-            tail_rows=0 if idx is None else len(idx), n_real=n_real,
-            # what the pre-device-compaction bookkeeping shipped host-ward
-            # per batch (the full wave5) — off the aval, no transfer
-            wave5_bytes=int(np.prod(det.wave5.shape))
-            * det.wave5.dtype.itemsize)
+            h2d_bytes=timings.get("h2d_bytes", 0)
+            + (idx.nbytes if n_real else 0),
+            d2h_bytes=keep.nbytes,
+            tail_rows=0 if idx is None else len(idx), n_real=n_real)
         return _PendingTail(det, out, n_real, wid, extra, src_bytes,
                             timings)
 
     def _emit(self, pend: _PendingTail) -> BatchResult:
         """Block on (the remainder of) the cleaned readback and build the
         result. Padded rows are sliced off here — and they are zero rows
-        from the fill gather, never repeats of real audio."""
-        t0 = time.perf_counter()
-        with obs_tracing.span("emit", wid=pend.wid):
+        from the fill gather, never repeats of real audio. The wait splits
+        in two: the tail program finishing on the device, then the rest of
+        the copy back (started at dispatch) and its host-side re-layout."""
+        t, wid = pend.timings, pend.wid
+        with obs_tracing.timed("emit", t, "emit_s", wid=wid):
             if pend.out is None:
                 cleaned = np.zeros((0, pend.det.wave5.shape[-1]), np.float32)
             else:
-                cleaned = np.asarray(pend.out)[:pend.n_real]
-                pend.timings["d2h_bytes"] += pend.out.nbytes
-        pend.timings["emit_s"] = time.perf_counter() - t0
-        # the pre-device-compaction boundary for THIS batch: full wave5 +
-        # mask down, the LINEAR-padded survivor batch up, the same padded
-        # tail output down (the old path sliced [:n_real] only after the
-        # full transfer) — its actual cost on this stream, not a model
-        lin_rows = SCHED.quantize_survivors(
-            pend.n_real, pend.det.keep.size, self.pad_multiple,
-            "linear") if pend.n_real else 0
-        row_bytes = cleaned.shape[-1] * cleaned.dtype.itemsize
-        pend.timings["old_boundary_bytes"] = (
-            pend.timings["wave5_bytes"] + pend.det.keep.size
-            + 2 * lin_rows * row_bytes)
+                with obs_tracing.timed("tail_wait", t, "tail_wait_s",
+                                       wid=wid):
+                    pend.out.block_until_ready()
+                with obs_tracing.timed("d2h_wait", t, "d2h_wait_s",
+                                       wid=wid):
+                    cleaned = np.asarray(pend.out)[:pend.n_real]
+                t["d2h_bytes"] += pend.out.nbytes
         res = BatchResult(cleaned=cleaned, det=pend.det,
                           n_kept=pend.n_real, wid=pend.wid,
                           labels=pend.extra, src_bytes=pend.src_bytes,
@@ -566,7 +573,9 @@ class TwoPhasePlan(ExecutionPlan):
 
     def __call__(self, audio) -> BatchResult:
         x = _to_device(audio, self.rules)
-        return self._finish(self.detect(x), src_bytes=int(x.nbytes))
+        h2d = 0 if isinstance(audio, jax.Array) else int(x.nbytes)
+        return self._finish(self.detect(x), src_bytes=int(x.nbytes),
+                            timings={"h2d_bytes": h2d})
 
 
 class AsyncPlan(TwoPhasePlan):
@@ -610,16 +619,18 @@ class AsyncPlan(TwoPhasePlan):
             return res
 
         for wid, chunks, extra in _iter_batches(batches):
-            t0 = time.perf_counter()
-            with obs_tracing.span("detect_dispatch", wid=wid):
+            timings = {"in_flight": len(dets) + 1}
+            with obs_tracing.timed("detect_dispatch", timings, "dispatch_s",
+                                   wid=wid):
                 owned = not isinstance(chunks, jax.Array)
-                x = _to_device(chunks, self.rules)
-                det = self._detect_donated(x) if owned and self.donate \
-                    else self.detect(x)               # async dispatch
-                if hasattr(det.keep, "copy_to_host_async"):
-                    det.keep.copy_to_host_async()     # prefetch the mask
-            timings = {"dispatch_s": time.perf_counter() - t0,
-                       "in_flight": len(dets) + 1}
+                with obs_tracing.span("h2d_put", wid=wid):
+                    x = _to_device(chunks, self.rules)
+                with obs_tracing.span("detect_enqueue", wid=wid):
+                    det = self._detect_donated(x) if owned and self.donate \
+                        else self.detect(x)           # async dispatch
+                    if hasattr(det.keep, "copy_to_host_async"):
+                        det.keep.copy_to_host_async()  # prefetch the mask
+            timings["h2d_bytes"] = int(x.nbytes) if owned else 0
             dets.append((det, wid, extra, int(x.nbytes), timings))
             if len(dets) > self.depth:
                 start_oldest_tail()

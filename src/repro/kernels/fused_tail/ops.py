@@ -158,7 +158,10 @@ def fused_tail(wave, idx, cfg, hpf=False, tile: TailConfig | None = None):
 
 
 def _kernel_path(wave, idx, cfg, hpf, tc, interpret):
-    packed = K.fused_tail_pallas(wave, idx, cfg, hpf, tc.frame_block,
-                                 interpret=interpret,
-                                 vmem_limit_bytes=VMEM_BUDGET)
-    return K.finish(packed, wave.shape[1], cfg)
+    # named apart, so a device trace tells the kernel from the resynthesis
+    with jax.named_scope("kernel"):
+        packed = K.fused_tail_pallas(wave, idx, cfg, hpf, tc.frame_block,
+                                     interpret=interpret,
+                                     vmem_limit_bytes=VMEM_BUDGET)
+    with jax.named_scope("istft"):
+        return K.finish(packed, wave.shape[1], cfg)

@@ -278,10 +278,8 @@ def main(argv=None):
               f"overlapped dispatches (max in-flight "
               f"{report['max_in_flight']}), host boundary "
               f"{report['d2h_bytes_per_batch'] / 2**20:.2f} MB down + "
-              f"{report['h2d_bytes_per_batch'] / 2**10:.1f} KB up per "
-              f"batch (the old host-compaction round-trip moved "
-              f"{report['old_boundary_bytes_per_batch'] / 2**20:.2f} MB "
-              f"on this stream)")
+              f"{report['h2d_bytes_per_batch'] / 2**20:.2f} MB up per "
+              f"batch")
     if cached is not None and cached.stats is not None:
         print(f"store: {cached.stats}")
     if args.store_max_bytes is not None and cached is not None \
@@ -334,13 +332,6 @@ def pipeline_report(timings):
                                      for t in timings) / n
     rep["h2d_bytes_per_batch"] = sum(t.get("h2d_bytes", 0)
                                      for t in timings) / n
-    # the counterfactual: what the old host-compaction bookkeeping moved
-    # per batch on THIS stream (full wave5 + mask down, survivor batch
-    # up, cleaned down — measured per batch, not a 2x-full-batch model)
-    rep["old_boundary_bytes_per_batch"] = sum(
-        t.get("old_boundary_bytes", 0) for t in timings) / n
-    rep["full_batch_bytes"] = sum(t.get("wave5_bytes", 0)
-                                  for t in timings) / n
     return rep
 
 

@@ -27,8 +27,18 @@ Event kinds used:
 (pid, tid).  The smoke gate and tests call it so a Perfetto-breaking
 regression fails CI, not a human.
 
-Zero-cost-when-off: the module-level tracer defaults to `NULL_TRACER`,
-whose `span()` returns a shared no-op context manager.
+Spans also reach the profiler: while a `jax.profiler` session records,
+every `span()` opens a `jax.profiler.TraceAnnotation` named `repro.<name>`
+with its args as metadata, so it lands on the trace's host plane beside
+the device ops and the runtime's transfer events.  The profiler stamps
+its host events on the wall clock, as `_now_us` does, so the two sinks
+share one timebase.  `timed()` is a span that also writes its block's
+duration into a timings dict, so a timing key and its span are one
+measurement.
+
+Zero-cost-when-off: the module-level tracer defaults to `NULL_TRACER`;
+with no profiler session either, `span()` returns a shared no-op context
+manager after the profiler's own inactive check.
 """
 from __future__ import annotations
 
@@ -38,10 +48,16 @@ import threading
 import time
 import uuid
 
+from jax.profiler import TraceAnnotation
+
+# profiler spans are named under this prefix
+PREFIX = "repro."
+
 
 def _now_us():
     # Wall-clock (not monotonic) so master and worker events share a
-    # comparable timebase across processes.
+    # comparable timebase across processes; the profiler's host events
+    # are stamped on the same clock.
     return time.time() * 1e6
 
 
@@ -58,21 +74,53 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+def _profiler_span(name, args):
+    """The profiler's annotation for span `name`, or the no-op span when
+    no profiler session records."""
+    if not TraceAnnotation.is_enabled():
+        return _NULL_SPAN
+    return TraceAnnotation(PREFIX + name, **args)
+
+
 class _Span:
-    """Context manager emitting a B/E pair on one tracer."""
-    __slots__ = ("_tracer", "_name")
+    """Context manager emitting a B/E pair on one tracer, inside the
+    profiler's annotation of the same span."""
+    __slots__ = ("_tracer", "_name", "_prof")
 
     def __init__(self, tracer, name, args):
         self._tracer = tracer
         self._name = name
+        self._prof = _profiler_span(name, args)
         tracer._emit("B", name, args=args)
 
     def __enter__(self):
+        self._prof.__enter__()
         return self
 
     def __exit__(self, *exc):
         self._tracer._emit("E", self._name)
+        self._prof.__exit__(*exc)
         return False
+
+
+class _Timed:
+    """A span that writes its block's `perf_counter` duration into
+    `timings[key]`."""
+    __slots__ = ("_span", "_timings", "_key", "_t0")
+
+    def __init__(self, span_, timings, key):
+        self._span = span_
+        self._timings = timings
+        self._key = key
+
+    def __enter__(self):
+        self._span.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self._timings[self._key] = time.perf_counter() - self._t0
+        return self._span.__exit__(*exc)
 
 
 class Tracer:
@@ -195,7 +243,7 @@ class NullTracer:
     dropped = 0
 
     def span(self, name, **args):
-        return _NULL_SPAN
+        return _profiler_span(name, args)
 
     def complete(self, name, start_s, end_s=None, **args):
         pass
@@ -243,7 +291,13 @@ def set_tracer(tracer):
 
 def span(name, **args):
     t = _TRACER
-    return t.span(name, **args) if t.enabled else _NULL_SPAN
+    return t.span(name, **args) if t.enabled else _profiler_span(name, args)
+
+
+def timed(name, timings, key, **args):
+    """`span(name, **args)` whose block's duration also lands in
+    `timings[key]` (seconds, `time.perf_counter`)."""
+    return _Timed(span(name, **args), timings, key)
 
 
 def instant(name, **args):

@@ -14,6 +14,7 @@ from repro.core import scheduler as SCHED
 from repro.core.plans import JIT_CACHE, PLANS, AsyncPlan, Preprocessor
 from repro.data.loader import audio_batch_maker
 from repro.data.synthetic import generate_labelled
+from repro.obs import metrics as obs_metrics
 
 
 def _stream(seed, n_batches, batch_long_chunks=1):
@@ -168,13 +169,6 @@ def test_padded_rows_never_reach_cleaned():
     pre = Preprocessor(cfg, plan="async", pad_multiple=8)
     res = pre(jnp.asarray(chunks))
     assert 0 < res.n_kept == res.cleaned.shape[0]
-    # the old-boundary counterfactual honours the pad multiple: its two
-    # survivor legs moved the linear-PADDED batch, not n_real rows
-    lin = SCHED.quantize_survivors(res.n_kept, 12, 8, "linear")
-    assert lin > res.n_kept or res.n_kept % 8 == 0
-    assert res.timings["old_boundary_bytes"] == (
-        res.timings["wave5_bytes"] + 12
-        + 2 * lin * cfg.final_split_samples * 4)
     ref = Preprocessor(cfg, plan="fused")(jnp.asarray(chunks))
     np.testing.assert_allclose(res.cleaned, ref.cleaned,
                                rtol=1e-4, atol=1e-5)
@@ -196,29 +190,46 @@ def test_timings_record_pipeline_and_boundary_bytes():
     t = pre.plan.last_timings
     assert len(t) == 4 and [x["in_flight"] for x in t] == [1, 2, 3, 4]
     assert sum(1 for x in t if x["in_flight"] >= 2) >= 1
-    for x, r in zip(t, res):
+    for (_, (chunks, _)), x, r in zip(stream, t, res):
         for k in ("dispatch_s", "readback_s", "compact_s", "tail_s",
                   "emit_s"):
             assert x[k] >= 0.0
         assert x is r.timings
         # exact host-boundary accounting: the B-bool mask down, the padded
-        # cleaned batch down, the int32 index vector up — nothing else
-        # (the old bookkeeping moved the full wave5 down + survivors up)
+        # cleaned batch down; the host batch and the int32 index vector
+        # up — nothing else
         want = int(np.asarray(r.det.keep).size)          # bool mask bytes
         if x["n_real"]:
             want += x["tail_rows"] * cfg.final_split_samples * 4
         assert x["d2h_bytes"] == want
-        assert x["h2d_bytes"] == 4 * x["tail_rows"]
-        assert want + x["h2d_bytes"] < 2 * x["wave5_bytes"]
-        # the measured counterfactual: the old round-trip moved the full
-        # wave5 + mask down, the linear-PADDED survivor batch up and the
-        # same padded tail output down (it sliced only after transfer)
-        cap = int(np.asarray(r.det.keep).size)
-        lin = SCHED.quantize_survivors(x["n_real"], cap, 1, "linear")
-        assert x["old_boundary_bytes"] == (
-            x["wave5_bytes"] + cap
-            + 2 * lin * cfg.final_split_samples * 4)
-        assert x["d2h_bytes"] + x["h2d_bytes"] < x["old_boundary_bytes"]
+        assert chunks.dtype == np.float32
+        assert x["h2d_bytes"] == chunks.nbytes + 4 * x["tail_rows"]
+
+
+def test_emit_wait_splits_into_tail_wait_and_copy_back():
+    """`emit_s` holds the tail program's finish (`tail_wait_s`) and the
+    rest of the copy back (`d2h_wait_s`), recorded only for batches that
+    ran a tail, and both reach the registry's stage histogram."""
+    prev, reg = obs_metrics.get_registry(), obs_metrics.MetricsRegistry()
+    obs_metrics.set_registry(reg)
+    try:
+        res = list(Preprocessor(cfg, plan="async", depth=2,
+                                pad_multiple=1).run(_stream(25, 3)))
+    finally:
+        obs_metrics.set_registry(prev)
+    tails = [r for r in res if r.n_kept]
+    assert tails and len(tails) < len(res)      # seed 25: one batch all cut
+    for r in res:
+        x = r.timings
+        if not r.n_kept:
+            assert "tail_wait_s" not in x and "d2h_wait_s" not in x
+            continue
+        assert x["tail_wait_s"] >= 0.0 and x["d2h_wait_s"] >= 0.0
+        assert x["tail_wait_s"] + x["d2h_wait_s"] <= x["emit_s"]
+    hist = reg.histogram("plan_stage_seconds", "per-batch stage wall time",
+                         ("plan", "stage"))
+    for stage in ("tail_wait", "d2h_wait"):
+        assert hist.labels(plan="async", stage=stage).value == len(tails)
 
 
 def test_async_donate_forced_still_bit_identical():

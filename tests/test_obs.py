@@ -4,14 +4,18 @@ InProc vs Proc telemetry parity on the sharded plan, SIGKILL redelivery
 attribution across worker incarnations, the ring caps that replaced the
 unbounded in-memory ledgers, StoreStats mirroring, and the `metrics` RPC.
 """
+import dataclasses
+import glob
 import json
 import os
 
+import jax
 import numpy as np
 import pytest
 
 from repro.configs import SERF_AUDIO as cfg
 from repro.core.plans import TIMINGS_CAP, Preprocessor
+from repro.data.synthetic import generate_labelled
 from repro.obs import metrics as obs_metrics
 from repro.obs import telemetry as obs_telemetry
 from repro.obs import tracing as obs_tracing
@@ -134,6 +138,84 @@ def test_trace_propagation_parents_child_events():
     assert compute["args"]["trace"] == parent.trace_id
     validate_chrome_trace(evs)
     assert child.drain() == []         # drain pops
+
+
+# the plan's spans on the profiler's host plane, each child inside its
+# parent; the rest stand alone
+_PROFILED_SPANS = {"detect_dispatch": None, "h2d_put": "detect_dispatch",
+                   "detect_enqueue": "detect_dispatch", "mask_wait": None,
+                   "compact": None, "tail_enqueue": None, "emit": None,
+                   "tail_wait": "emit", "d2h_wait": "emit"}
+
+
+def _profiled_stream():
+    """Three batches of one 15 s stereo long chunk: bird song, silence
+    (removed whole, so it runs no tail), bird song."""
+    per = 3                                   # 5 s segments a long chunk
+    batches = []
+    for seed, probs in ((3, (1, 0, 0, 0)), (4, (0, 0, 0, 1)),
+                        (5, (1, 0, 0, 0))):
+        audio, _ = generate_labelled(seed, per, segment_s=5.0,
+                                     label_probs=probs)
+        batches.append(audio.transpose(1, 0, 2).reshape(1, 2, -1))
+    return list(enumerate((x, None) for x in batches))
+
+
+def test_plan_spans_reach_the_profiler_trace(tmp_path):
+    """Under a `jax.profiler` session the async plan's spans land on the
+    trace's host plane as `repro.<name>`, nested as the plan opens them,
+    each carrying its batch's `wid`; no Chrome tracer is installed."""
+    small = dataclasses.replace(cfg, long_split_s=15.0)
+    pre = Preprocessor(small, plan="async", depth=2, pad_multiple=1)
+    stream = _profiled_stream()
+    list(pre.run(stream))                     # compile outside the trace
+    assert not obs_tracing.get_tracer().enabled
+    with jax.profiler.trace(str(tmp_path)):
+        res = list(pre.run(stream))
+    assert not obs_tracing.TraceAnnotation.is_enabled()
+    path, = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = {}                                # (name, wid) -> (start, end)
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith(obs_tracing.PREFIX):
+                    name = ev.name[len(obs_tracing.PREFIX):]
+                    key = (name, {k: v for k, v in ev.stats}["wid"])
+                    assert key not in spans
+                    spans[key] = (ev.start_ns, ev.start_ns + ev.duration_ns)
+    kept = {r.wid for r in res if r.n_kept}
+    assert kept == {0, 2}
+    for r in res:
+        for name, parent in _PROFILED_SPANS.items():
+            if name in ("tail_wait", "d2h_wait") and r.wid not in kept:
+                assert (name, r.wid) not in spans
+                continue
+            lo, hi = spans[(name, r.wid)]
+            if parent is not None:
+                plo, phi = spans[(parent, r.wid)]
+                assert plo <= lo <= hi <= phi, (name, parent, r.wid)
+    assert {n for n, _ in spans} == set(_PROFILED_SPANS)
+
+
+def test_timed_span_writes_its_duration():
+    t = {}
+    with obs_tracing.timed("probe", t, "probe_s", wid=7):
+        pass
+    assert t["probe_s"] >= 0.0
+    tracer = Tracer()
+    obs_tracing.set_tracer(tracer)
+    try:
+        with obs_tracing.timed("probe", t, "again_s", wid=8):
+            pass
+    finally:
+        obs_tracing.set_tracer(None)
+    assert t["again_s"] >= 0.0
+    names = [(e["ph"], e["name"], e["args"].get("wid"))
+             for e in tracer.events]
+    assert names == [("B", "probe", 8), ("E", "probe", None)]
+    validate_chrome_trace(tracer.chrome())
 
 
 def test_validate_chrome_trace_rejects_bad_events():

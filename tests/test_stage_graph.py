@@ -2,7 +2,9 @@
 validation, plan equivalence across pad multiples (fused == two_phase ==
 streaming on survivors), compile-cache keying, and the serving glue."""
 import dataclasses
+import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -13,6 +15,7 @@ from repro.core.plans import (CompileCache, JIT_CACHE, PLANS, Preprocessor,
                               TwoPhasePlan)
 from repro.data.synthetic import generate_labelled
 from repro.distributed.sharding import ShardingRules
+from repro.kernels import backend
 
 
 def _long_chunks(seed, n_long):
@@ -28,6 +31,39 @@ def chunks():
 
 
 # ----------------------------------------------------------- registry/graph
+
+def _lowered_names(kind, *shapes):
+    """(module name, {first two name scopes under the jit of each op}) of
+    a phase lowered for `shapes`."""
+    pre = Preprocessor(cfg, plan="async")
+    args = [jax.ShapeDtypeStruct(s, d) for s, d in shapes]
+    text = jax.jit(pre.phase_fn(kind)).lower(*args).as_text(debug_info=True)
+    module = re.search(r"^module @(\S+)", text, re.M).group(1)
+    scopes = {"/".join(m.split("/")[:2]) for m in re.findall(
+        r'loc\("jit\(%s\)/([^"]*)"' % kind, text)}
+    return module, scopes
+
+
+def test_programs_and_stages_carry_their_names():
+    """Each phase's program is named `jit_<phase>`, and its ops sit under
+    the name scope of the stage that made them; the fused tail's kernel
+    call and its XLA resynthesis sit apart."""
+    long = ((1, 2, cfg.long_split_samples), jnp.float32)
+    module, scopes = _lowered_names("detect", long)
+    graph = PipelineGraph(cfg)
+    assert module == "jit_detect"
+    assert set(graph.names[:graph._cut()]) <= {s.split("/")[0]
+                                                for s in scopes}
+    rows = ((12, cfg.final_split_samples), jnp.float32)
+    idx = ((8,), jnp.int32)
+    module, scopes = _lowered_names("tail_idx", rows, idx)
+    assert module == "jit_tail_idx" and "mmse" in {s.split("/")[0]
+                                                   for s in scopes}
+    with backend.use("interpret"):
+        module, scopes = _lowered_names("tail_idx_fused", rows, idx)
+    assert module == "jit_tail_idx_fused"
+    assert {"fused_tail/kernel", "fused_tail/istft"} <= scopes
+
 
 def test_stage_registry_round_trip():
     """The paper's order is config DATA: every declared stage resolves in

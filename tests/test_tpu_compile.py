@@ -11,6 +11,7 @@ around these compiles — an entry written for a described chip cannot be
 read back without one.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,8 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs import SERF_AUDIO as cfg
+from repro.core.plans import Preprocessor
+from repro.kernels import backend
 from repro.kernels.fir_hpf import kernel as FK
 from repro.kernels.fir_hpf import ref as FR
 from repro.kernels.fused_tail import kernel as FTK
@@ -116,3 +119,21 @@ def test_fused_tail_vmem_model_is_enough(compile_for_v5e, tc, hpf):
         lambda w, i: FTK.fused_tail_pallas(w, i, cfg, hpf, tc.frame_block,
                                            vmem_limit_bytes=need),
         [((24, S5), F32), ((16,), I32)])
+
+
+def test_scoped_fused_tail_keeps_its_kernel_name(compile_for_v5e, monkeypatch):
+    """The tail program as the plans jit it, `PipelineGraph.
+    tail_indexed_fused` with its name scopes, at the SERF geometry: the
+    program reads `jit_tail_idx_fused`, the Pallas call is still the
+    instruction `fused_tail.<n>` that a device trace names the kernel by,
+    and the kernel and the resynthesis carry their scopes."""
+    # what the kernels resolve to on the chip: compiled Pallas
+    monkeypatch.setattr(backend, "resolve", lambda: (True, False))
+    fn = Preprocessor(cfg, plan="async").phase_fn("tail_idx_fused")
+    text = compile_for_v5e(fn, [((24, S5), F32), ((16,), I32)]).as_text()
+    assert text.startswith("HloModule jit_tail_idx_fused,")
+    calls = re.findall(r"^\s*%(\S+) = .*custom_call_target=\"tpu_custom_call\""
+                       r".*op_name=\"([^\"]*)\"", text, re.M)
+    assert [re.sub(r"\.\d+$", "", c) for c, _ in calls] == ["fused_tail"]
+    assert "/fused_tail/kernel/" in calls[0][1]
+    assert re.search(r'op_name="jit\(tail_idx_fused\)/fused_tail/istft/', text)
