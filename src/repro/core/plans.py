@@ -28,8 +28,10 @@ the execution strategy. Six plans, and when to pick each:
                       ever cross the host boundary), power-of-two survivor
                       buckets (O(log B) tail compiles instead of one per
                       count), optional buffer donation, and double-buffered
-                      cleaned readback. Per-batch `BatchResult.timings`
-                      record dispatch/readback/compact/tail/emit plus the
+                      cleaned readback finished on a reader thread, so the
+                      loop sends the next batch first. Per-batch
+                      `BatchResult.timings` record dispatch/readback/
+                      compact/tail/emit plus the
                       in-flight depth and transferred bytes. Pick for long
                       single-host streams; `depth` 2-4 is enough to hide
                       mask readback on one device — go deeper only when
@@ -147,9 +149,10 @@ are off. What each plan emits and where it lands:
     and `repro.<name>` on the `jax.profiler` trace's host plane while a
     profiler session records), each carrying the batch's `wid`:
     `detect_dispatch` (async window fill: `h2d_put`, `detect_enqueue`),
-    `mask_wait`, `compact`, `tail_enqueue`, `emit` (blocking cleaned
-    readback: `tail_wait`, `d2h_wait`), `fused_batch`; the `_s` timings
-    are these spans' durations (`obs_tracing.timed`);
+    `mask_wait`, `compact`, `tail_enqueue`, `emit` (the emitting loop's
+    wait for the cleaned rows), `tail_wait` and `d2h_wait` (the readback,
+    on `AsyncPlan`'s reader thread while input flows), `fused_batch`; the
+    `_s` timings are these spans' durations (`obs_tracing.timed`);
     ShardedPlan's proc master additionally marks `accept` (result
     accepted at the completion gate) and `emit_gated` instants, whose
     gap makes straggler-blocked emission visible. Worker processes
@@ -204,10 +207,12 @@ compile, per-host meshes key separately by device ids.
 from __future__ import annotations
 
 import collections
+import itertools
 import operator
 import os
 import threading
 import time
+from concurrent.futures import ThreadPoolExecutor
 from dataclasses import dataclass, field, replace
 
 import jax
@@ -342,10 +347,15 @@ class BatchResult:
     #   readback_s  mask_wait: blocking part of the keep-mask readback
     #   compact_s   compact: host index bookkeeping (the "master" role)
     #   tail_s      tail_enqueue: tail enqueue + async cleaned-copy start
-    #   emit_s      emit: blocking part of the cleaned readback, of it
-    #     tail_wait_s  tail_wait: until the tail's output exists on device
-    #     d2h_wait_s   d2h_wait: the rest of the copy back and its host
-    #                  re-layout (both only for batches that ran a tail)
+    #   emit_s      emit: the emitting loop's blocking wait for the cleaned
+    #               rows (the readback itself inline; under AsyncPlan's
+    #               reader, only what that thread has not finished)
+    #   tail_wait_s tail_wait: until the tail's output exists on device
+    #   d2h_wait_s  d2h_wait: the rest of the copy back and its host
+    #               re-layout (both on whichever thread reads back)
+    #   emit_ready  AsyncPlan: 1.0 when the rows were on the host already
+    #               as the loop took the batch, else 0.0 (these three only
+    #               for batches that ran a tail)
     #   in_flight   detect batches in the window when this one dispatched
     #   d2h_bytes / h2d_bytes   host-boundary traffic this batch caused
     #               (h2d counts the batch itself when it came from host)
@@ -459,8 +469,8 @@ class FusedPlan(ExecutionPlan):
 @dataclass
 class _PendingTail:
     """A batch whose tail is dispatched but not yet read back: everything
-    `_emit` needs, held while the device works and the cleaned rows stream
-    host-ward via copy_to_host_async."""
+    `_readback` and `_emit` need, held while the device works and the
+    cleaned rows stream host-ward via copy_to_host_async."""
     det: PipelineOutput
     out: object                     # device cleaned batch (None: 0 kept)
     n_real: int
@@ -541,24 +551,25 @@ class TwoPhasePlan(ExecutionPlan):
         return _PendingTail(det, out, n_real, wid, extra, src_bytes,
                             timings)
 
-    def _emit(self, pend: _PendingTail) -> BatchResult:
-        """Block on (the remainder of) the cleaned readback and build the
-        result. Padded rows are sliced off here — and they are zero rows
+    def _readback(self, pend: _PendingTail) -> np.ndarray:
+        """Block on (the remainder of) the cleaned readback and return the
+        real rows. Padded rows are sliced off here — and they are zero rows
         from the fill gather, never repeats of real audio. The wait splits
         in two: the tail program finishing on the device, then the rest of
-        the copy back (started at dispatch) and its host-side re-layout."""
+        the copy back (started at dispatch) and its host-side re-layout.
+        Touches only `pend`, so it may run on a thread of its own."""
+        if pend.out is None:
+            return np.zeros((0, pend.det.wave5.shape[-1]), np.float32)
         t, wid = pend.timings, pend.wid
-        with obs_tracing.timed("emit", t, "emit_s", wid=wid):
-            if pend.out is None:
-                cleaned = np.zeros((0, pend.det.wave5.shape[-1]), np.float32)
-            else:
-                with obs_tracing.timed("tail_wait", t, "tail_wait_s",
-                                       wid=wid):
-                    pend.out.block_until_ready()
-                with obs_tracing.timed("d2h_wait", t, "d2h_wait_s",
-                                       wid=wid):
-                    cleaned = np.asarray(pend.out)[:pend.n_real]
-                t["d2h_bytes"] += pend.out.nbytes
+        with obs_tracing.timed("tail_wait", t, "tail_wait_s", wid=wid):
+            pend.out.block_until_ready()
+        with obs_tracing.timed("d2h_wait", t, "d2h_wait_s", wid=wid):
+            cleaned = np.asarray(pend.out)[:pend.n_real]
+        t["d2h_bytes"] += pend.out.nbytes
+        return cleaned
+
+    def _emit(self, pend: _PendingTail, cleaned) -> BatchResult:
+        """The batch's result, mirrored into the metrics registry."""
         res = BatchResult(cleaned=cleaned, det=pend.det,
                           n_kept=pend.n_real, wid=pend.wid,
                           labels=pend.extra, src_bytes=pend.src_bytes,
@@ -568,8 +579,10 @@ class TwoPhasePlan(ExecutionPlan):
 
     def _finish(self, det: PipelineOutput, wid=None, extra=None,
                 src_bytes=0, timings=None):
-        return self._emit(self._start_tail(det, wid, extra, src_bytes,
-                                           timings))
+        pend = self._start_tail(det, wid, extra, src_bytes, timings)
+        with obs_tracing.timed("emit", pend.timings, "emit_s", wid=wid):
+            cleaned = self._readback(pend)
+        return self._emit(pend, cleaned)
 
     def __call__(self, audio) -> BatchResult:
         x = _to_device(audio, self.rules)
@@ -578,16 +591,23 @@ class TwoPhasePlan(ExecutionPlan):
                             timings={"h2d_bytes": h2d})
 
 
+# name prefix of AsyncPlan's readback thread
+READER_THREAD = "repro-readback"
+
+
 class AsyncPlan(TwoPhasePlan):
     """Depth-K asynchronous streaming executor: a bounded window of `depth`
     detection batches dispatched ahead, each keep mask prefetched to host
     the moment its detect is enqueued (double-buffered mask readback), the
     tail gathering survivors device-side, and one finished tail held back
     so its cleaned rows stream host-ward while the next batch computes
-    (double-buffered emission). Defaults to power-of-two survivor buckets
-    and, on non-CPU backends, donated detect/tail buffers. Emission is
-    strictly input order; `last_timings` keeps the per-batch records of the
-    most recent run()."""
+    (double-buffered emission). While input flows, each cleaned readback
+    past that hold-back runs on one reader thread (FIFO), so the loop sends
+    the next batch instead of waiting on the copy back; it blocks only
+    when a further tail is outstanding. Defaults to power-of-two survivor
+    buckets and, on non-CPU backends, donated detect/tail buffers. Emission
+    is strictly input order; `last_timings` keeps the per-batch records of
+    the most recent run()."""
     name = "async"
 
     def __init__(self, graph, rules=NULL_RULES, pad_multiple=1, depth=2,
@@ -598,8 +618,9 @@ class AsyncPlan(TwoPhasePlan):
         self.depth = max(1, int(depth))
         # dispatched tails retained before emission: 1 double-buffers the
         # cleaned readback behind the next batch (+1 batch of emission
-        # latency and one extra resident batch); 0 emits each result the
-        # moment its tail is dispatched (the pre-PR streaming schedule)
+        # latency and one extra resident batch, +1 more while the reader
+        # works); 0 reads each result back inline the moment its tail is
+        # dispatched (the historical streaming schedule, never the reader)
         self.emit_buffer = max(0, int(emit_buffer))
         # bounded ring: the registry holds the aggregate (plan_stage_seconds
         # et al. via _record_batch); this keeps only recent history
@@ -608,40 +629,74 @@ class AsyncPlan(TwoPhasePlan):
     def run(self, batches):
         self.last_timings = collections.deque(maxlen=TIMINGS_CAP)
         dets = collections.deque()       # detect window (<= depth)
-        tails = collections.deque()      # dispatched tails (<= 2)
+        tails = collections.deque()      # [pending tail, readback future]
+        reader = None                    # started at the first hand-off
 
         def start_oldest_tail():
-            tails.append(self._start_tail(*dets.popleft()))
+            tails.append([self._start_tail(*dets.popleft()), None])
 
-        def emit_oldest():
-            res = self._emit(tails.popleft())
-            self.last_timings.append(res.timings)
-            return res
+        def take_oldest():
+            """The oldest tail as a result: its readback's future, or the
+            readback itself when it was never handed off. `emit_s` is the
+            loop's own wait; `emit_ready` says the rows were on the host
+            already."""
+            pend, fut = tails.popleft()
+            t = pend.timings
+            with obs_tracing.timed("emit", t, "emit_s", wid=pend.wid):
+                ready = fut is not None and fut.done()
+                cleaned = self._readback(pend) if fut is None \
+                    else fut.result()
+            if pend.out is not None:
+                t["emit_ready"] = float(ready)
+            self.last_timings.append(t)
+            return self._emit(pend, cleaned)
 
-        for wid, chunks, extra in _iter_batches(batches):
-            timings = {"in_flight": len(dets) + 1}
-            with obs_tracing.timed("detect_dispatch", timings, "dispatch_s",
-                                   wid=wid):
-                owned = not isinstance(chunks, jax.Array)
-                with obs_tracing.span("h2d_put", wid=wid):
-                    x = _to_device(chunks, self.rules)
-                with obs_tracing.span("detect_enqueue", wid=wid):
-                    det = self._detect_donated(x) if owned and self.donate \
-                        else self.detect(x)           # async dispatch
-                    if hasattr(det.keep, "copy_to_host_async"):
-                        det.keep.copy_to_host_async()  # prefetch the mask
-            timings["h2d_bytes"] = int(x.nbytes) if owned else 0
-            dets.append((det, wid, extra, int(x.nbytes), timings))
-            if len(dets) > self.depth:
+        def settle(feeding):
+            """Yield what is due. While input flows (or once the reader
+            runs), the tails past the hold-back go to the reader, ready ones
+            are yielded, and the loop blocks only past one more tail; else
+            the oldest past the hold-back is read back inline."""
+            nonlocal reader
+            hold = self.emit_buffer
+            if hold and len(tails) > hold and (feeding or reader is not None):
+                if reader is None:
+                    reader = ThreadPoolExecutor(
+                        1, thread_name_prefix=READER_THREAD)
+                for e in itertools.islice(tails, len(tails) - hold):
+                    if e[1] is None:
+                        e[1] = reader.submit(self._readback, e[0])
+                hold += 1
+            while len(tails) > hold or \
+                    (tails and tails[0][1] is not None and tails[0][1].done()):
+                yield take_oldest()
+
+        try:
+            for wid, chunks, extra in _iter_batches(batches):
+                timings = {"in_flight": len(dets) + 1}
+                with obs_tracing.timed("detect_dispatch", timings,
+                                       "dispatch_s", wid=wid):
+                    owned = not isinstance(chunks, jax.Array)
+                    with obs_tracing.span("h2d_put", wid=wid):
+                        x = _to_device(chunks, self.rules)
+                    with obs_tracing.span("detect_enqueue", wid=wid):
+                        det = self._detect_donated(x) \
+                            if owned and self.donate \
+                            else self.detect(x)       # async dispatch
+                        if hasattr(det.keep, "copy_to_host_async"):
+                            det.keep.copy_to_host_async()  # prefetch mask
+                timings["h2d_bytes"] = int(x.nbytes) if owned else 0
+                dets.append((det, wid, extra, int(x.nbytes), timings))
+                if len(dets) > self.depth:
+                    start_oldest_tail()
+                yield from settle(feeding=True)
+            while dets:
                 start_oldest_tail()
-            while len(tails) > self.emit_buffer:
-                yield emit_oldest()
-        while dets:
-            start_oldest_tail()
-            while len(tails) > self.emit_buffer:
-                yield emit_oldest()
-        while tails:
-            yield emit_oldest()
+                yield from settle(feeding=False)
+            while tails:
+                yield take_oldest()
+        finally:
+            if reader is not None:       # early stop or error: no thread
+                reader.shutdown(cancel_futures=True)   # left behind
 
 
 class StreamingPlan(AsyncPlan):

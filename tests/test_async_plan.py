@@ -1,8 +1,11 @@
 """Device-resident compaction + the depth-K async executor: equivalence
 with two_phase across keep rates, input-order exactly-once emission,
-bucketed tail-compile accounting, zero-fill padding hygiene, and the
-per-batch pipeline timing records."""
+bucketed tail-compile accounting, zero-fill padding hygiene, the
+per-batch pipeline timing records, and the reader thread that finishes
+each cleaned readback off the dispatching loop."""
 import dataclasses
+import threading
+import time
 import warnings
 
 import jax.numpy as jnp
@@ -11,7 +14,8 @@ import pytest
 
 from repro.configs import SERF_AUDIO as cfg
 from repro.core import scheduler as SCHED
-from repro.core.plans import JIT_CACHE, PLANS, AsyncPlan, Preprocessor
+from repro.core.plans import (JIT_CACHE, PLANS, READER_THREAD, AsyncPlan,
+                              Preprocessor)
 from repro.data.loader import audio_batch_maker
 from repro.data.synthetic import generate_labelled
 from repro.obs import metrics as obs_metrics
@@ -207,9 +211,11 @@ def test_timings_record_pipeline_and_boundary_bytes():
 
 
 def test_emit_wait_splits_into_tail_wait_and_copy_back():
-    """`emit_s` holds the tail program's finish (`tail_wait_s`) and the
-    rest of the copy back (`d2h_wait_s`), recorded only for batches that
-    ran a tail, and both reach the registry's stage histogram."""
+    """Read back inline (three batches at depth 2 reach the reader only in
+    the final drain, where it is never started), `emit_s` holds the tail
+    program's finish (`tail_wait_s`) and the rest of the copy back
+    (`d2h_wait_s`), recorded only for batches that ran a tail, and both
+    reach the registry's stage histogram."""
     prev, reg = obs_metrics.get_registry(), obs_metrics.MetricsRegistry()
     obs_metrics.set_registry(reg)
     try:
@@ -223,9 +229,11 @@ def test_emit_wait_splits_into_tail_wait_and_copy_back():
         x = r.timings
         if not r.n_kept:
             assert "tail_wait_s" not in x and "d2h_wait_s" not in x
+            assert "emit_ready" not in x
             continue
         assert x["tail_wait_s"] >= 0.0 and x["d2h_wait_s"] >= 0.0
         assert x["tail_wait_s"] + x["d2h_wait_s"] <= x["emit_s"]
+        assert x["emit_ready"] == 0.0
     hist = reg.histogram("plan_stage_seconds", "per-batch stage wall time",
                          ("plan", "stage"))
     for stage in ("tail_wait", "d2h_wait"):
@@ -246,3 +254,119 @@ def test_async_donate_forced_still_bit_identical():
                            pad_multiple=1)
         got = np.concatenate([r.cleaned for r in pre.run(stream)])
     np.testing.assert_array_equal(got, ref_cleaned)
+
+
+# ------------------------------------------- reader thread (emission)
+
+def _readers():
+    return [t for t in threading.enumerate()
+            if t.name.startswith(READER_THREAD)]
+
+
+@pytest.fixture(scope="module")
+def long_stream():
+    """8 batches, with the references the async plan must match bit for
+    bit: StreamingPlan's run (inline emission) and per-batch __call__."""
+    stream = _stream(28, 8)
+    streaming = list(Preprocessor(cfg, plan="streaming",
+                                  pad_multiple=1).run(stream))
+    call = Preprocessor(cfg, plan="async", pad_multiple=1)
+    calls = [call(jnp.asarray(chunks)) for _, (chunks, _) in stream]
+    return stream, streaming, calls
+
+
+@pytest.mark.parametrize("depth", [1, 2, 4])
+def test_reader_emission_in_order_and_bit_identical(long_stream, depth):
+    """With the readback on the reader thread, run() still yields input
+    order, bitwise equal to StreamingPlan and to per-batch __call__, and
+    every batch that ran a tail says whether its rows were ready."""
+    stream, streaming, calls = long_stream
+    pre = Preprocessor(cfg, plan="async", depth=depth, pad_multiple=1)
+    got, saw_reader = [], False
+    for r in pre.run(stream):
+        saw_reader |= bool(_readers())
+        got.append(r)
+    assert saw_reader, "the reader never started on an 8-batch stream"
+    assert not _readers()
+    assert [r.wid for r in got] == list(range(len(stream)))
+    assert any(r.n_kept for r in got) and not all(r.n_kept for r in got)
+    for r, s, c in zip(got, streaming, calls):
+        for ref in (s, c):
+            np.testing.assert_array_equal(np.asarray(r.det.keep),
+                                          np.asarray(ref.det.keep))
+            np.testing.assert_array_equal(r.cleaned, ref.cleaned)
+        assert r.n_kept == r.cleaned.shape[0] == s.n_kept
+        if r.n_kept:
+            assert r.timings["emit_ready"] in (0.0, 1.0)
+            assert r.timings["tail_wait_s"] >= 0.0
+            assert r.timings["d2h_wait_s"] >= 0.0
+        else:
+            assert "emit_ready" not in r.timings
+
+
+@pytest.mark.parametrize("emit_buffer", [1, 2])
+def test_slow_readback_bounds_outstanding_tails(monkeypatch, emit_buffer):
+    """A reader slower than the loop holds the loop back: no more than
+    emit_buffer + 2 dispatched tails are ever outstanding."""
+    plan = AsyncPlan(Preprocessor(cfg, pad_multiple=1).graph, depth=1,
+                     emit_buffer=emit_buffer)
+    readback, start_tail = plan._readback, plan._start_tail
+    started, emitted, most = [0], [0], [0]
+
+    def slow_readback(pend):
+        time.sleep(0.3)
+        return readback(pend)
+
+    def counted_start_tail(*a):
+        started[0] += 1
+        most[0] = max(most[0], started[0] - emitted[0])
+        return start_tail(*a)
+
+    monkeypatch.setattr(plan, "_readback", slow_readback)
+    monkeypatch.setattr(plan, "_start_tail", counted_start_tail)
+    stream = _stream(28, 8)
+    for r in plan.run(stream):
+        assert r.wid == emitted[0]
+        emitted[0] += 1
+    assert emitted[0] == started[0] == len(stream)
+    assert emit_buffer + 1 <= most[0] <= emit_buffer + 2
+
+
+class _Boom(RuntimeError):
+    pass
+
+
+@pytest.mark.parametrize("stop", ["break", "error"])
+def test_no_reader_thread_outlives_run(monkeypatch, stop):
+    """Stopping early, or an error inside a readback, leaves no reader
+    thread alive; the error surfaces from the next() for that batch."""
+    pre = Preprocessor(cfg, plan="async", depth=1, pad_multiple=1)
+    readback = pre.plan._readback
+
+    def failing_readback(pend):
+        if pend.wid == 3:
+            raise _Boom("readback of batch 3")
+        return readback(pend)
+
+    monkeypatch.setattr(pre.plan, "_readback", failing_readback)
+    it = pre.run(_stream(28, 8))
+    got = [next(it).wid for _ in range(3)]
+    assert got == [0, 1, 2] and _readers()
+    if stop == "break":
+        it.close()
+    else:
+        with pytest.raises(_Boom, match="batch 3"):
+            next(it)
+    assert not _readers()
+
+
+@pytest.mark.parametrize("plan, n", [("async", 1), ("streaming", 8)])
+def test_reader_never_starts(plan, n):
+    """A one-batch run() (the serving pump) reaches only the final drain,
+    and StreamingPlan reads every result back inline: neither starts the
+    reader."""
+    res = []
+    for r in Preprocessor(cfg, plan=plan, pad_multiple=1).run(_stream(28, n)):
+        assert not _readers()
+        res.append(r)
+    assert [r.wid for r in res] == list(range(n))
