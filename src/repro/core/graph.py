@@ -159,19 +159,25 @@ def per_chunk(rules, fn, *args, replicated=()):
     Without a mesh (or on one device) this is `fn(*args)`. When `rules`
     shard "chunks" over several devices, XLA cannot partition the Pallas
     kernels inside the stage chain, so the chain runs under `shard_map`:
-    dim 0 of each argument is split over the chunk axes (arguments whose
-    position is in `replicated` go whole to every device) and every output
-    leaf is reassembled along dim 0. The stages then see NULL_RULES."""
+    dim 0 of each argument is split over the chunk axes and every output
+    leaf is reassembled along dim 0. An argument whose position is in
+    `replicated` enters split like the others and is then gathered whole
+    on every device, by one explicit all-gather under the `gather` scope,
+    so the exchange has a name in the HLO and in a device trace. The
+    stages then see NULL_RULES."""
     axes, n = chunk_split(rules)
     if n == 1:
         return fn(*args)
-    for i, a in enumerate(args):
-        if i not in replicated:
-            check_split(a.shape[0], rules)
-    mesh = rules.mesh
-    in_specs = tuple(P() if i in replicated else P(axes)
-                     for i in range(len(args)))
-    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+    for a in args:
+        check_split(a.shape[0], rules)
+
+    def local(*xs):
+        with jax.named_scope("gather"):
+            xs = [jax.lax.all_gather(x, axes, tiled=True)
+                  if i in replicated else x for i, x in enumerate(xs)]
+        return fn(*xs)
+    return jax.shard_map(local, mesh=rules.mesh,
+                         in_specs=(P(axes),) * len(args),
                          out_specs=P(axes), check_vma=False)(*args)
 
 
@@ -582,10 +588,11 @@ class PipelineGraph:
                 f"post-removal stages {self.names[self._cut():]} are not "
                 "the canonical fused tail; use tail_indexed")
         # on a mesh every device cleans an equal share of the survivor
-        # list out of the whole batch (gathered to each device once), so
-        # survivor imbalance between the devices' own chunks costs nothing.
-        # Scoped `fused_tail`; inside it the kernel path scopes the Pallas
-        # call (`kernel`) apart from the XLA resynthesis (`istft`).
+        # list out of the whole batch (all-gathered to each device once,
+        # `fused_tail/gather`), so survivor imbalance between the devices'
+        # own chunks costs nothing. Scoped `fused_tail`; inside it the
+        # kernel path scopes the Pallas call (`kernel`) apart from the XLA
+        # resynthesis (`istft`).
         with jax.named_scope("fused_tail"):
             return per_chunk(
                 rules, lambda w, i: fused_tail_ops.fused_tail(

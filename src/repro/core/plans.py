@@ -221,7 +221,7 @@ import numpy as np
 
 from repro.core import scheduler as SCHED
 from repro.core.graph import (GraphValidationError, PipelineGraph,
-                              PipelineOutput, check_split)
+                              PipelineOutput, check_split, chunk_split)
 from repro.data.loader import ShardedLoader, make_shard_pool
 from repro.data.queue import WorkQueue
 from repro.dist.data_plane import StoreDataPlane
@@ -359,6 +359,11 @@ class BatchResult:
     #   in_flight   detect batches in the window when this one dispatched
     #   d2h_bytes / h2d_bytes   host-boundary traffic this batch caused
     #               (h2d counts the batch itself when it came from host)
+    #   gather_bytes  bytes each device received in the fused tail's
+    #               all-gather of the batch ((n-1)/n of it; 0 on one
+    #               device and for a batch with no tail)
+    #   kept_skew   the most kept chunks among one device's own chunks
+    #               over the mean of the devices (1.0 on one device)
     #   tail_rows / n_real      padded tail batch rows vs real survivors
 
 
@@ -367,6 +372,9 @@ class BatchResult:
 # keeps the aggregate view, so the attribute is a bounded recent-history
 # ring.
 TIMINGS_CAP = 4096
+
+# kept_skew is 1.0 when even and at most the device count
+SKEW_BUCKETS = (1.0, 1.05, 1.1, 1.25, 1.5, 2.0, 3.0, 4.0, 8.0)
 
 _STAGE_KEYS = ("dispatch_s", "readback_s", "compact_s", "tail_s", "emit_s",
                "tail_wait_s", "d2h_wait_s")
@@ -398,10 +406,18 @@ def _record_batch(plan_name, res: "BatchResult"):
             reg.histogram("plan_stage_seconds", "per-batch stage wall time",
                           ("plan", "stage")).labels(
                 plan=plan_name, stage=k[:-2]).observe(t[k])
-    for k in ("d2h_bytes", "h2d_bytes"):
+    for k, what in (("d2h_bytes", "host-boundary traffic"),
+                    ("h2d_bytes", "host-boundary traffic"),
+                    ("gather_bytes", "bytes each device received in the "
+                     "tail's all-gather")):
         if k in t:
-            reg.counter(f"plan_{k}_total", "host-boundary traffic",
+            reg.counter(f"plan_{k}_total", what,
                         ("plan",)).labels(**lab).inc(int(t[k]))
+    if "kept_skew" in t:
+        reg.histogram("plan_kept_skew", "kept chunks of the busiest "
+                      "device over the mean", ("plan",),
+                      buckets=SKEW_BUCKETS).labels(**lab).observe(
+            t["kept_skew"])
 
 
 class _StreamMeta:
@@ -533,6 +549,11 @@ class TwoPhasePlan(ExecutionPlan):
         with obs_tracing.timed("compact", timings, "compact_s", wid=wid):
             idx, n_real = SCHED.survivor_indices(keep, self.pad_multiple,
                                                  self.bucket)
+        # the fused tail all-gathers the batch to every device of a mesh:
+        # each receives the (n - 1) / n of it that it does not hold
+        _, n_dev = chunk_split(self.rules)
+        gather = det.wave5.nbytes * (n_dev - 1) // n_dev \
+            if n_real and self.fuse_tail else 0
         out = None
         with obs_tracing.timed("tail_enqueue", timings, "tail_s", wid=wid):
             if n_real:
@@ -546,7 +567,8 @@ class TwoPhasePlan(ExecutionPlan):
         timings.update(
             h2d_bytes=timings.get("h2d_bytes", 0)
             + (idx.nbytes if n_real else 0),
-            d2h_bytes=keep.nbytes,
+            d2h_bytes=keep.nbytes, gather_bytes=gather,
+            kept_skew=SCHED.kept_skew(keep, n_dev),
             tail_rows=0 if idx is None else len(idx), n_real=n_real)
         return _PendingTail(det, out, n_real, wid, extra, src_bytes,
                             timings)

@@ -109,6 +109,15 @@ def survivor_indices(keep_np, pad_multiple=1, bucket="pow2"):
     return out, n
 
 
+def kept_skew(keep_np, parts):
+    """Survivor imbalance over `parts` devices that each hold an equal
+    run of the batch's chunks: the largest part's count of kept chunks
+    over the mean count. 1.0 on one device or when nothing is kept."""
+    counts = np.asarray(keep_np).reshape(parts, -1).sum(1)
+    mean = counts.mean()
+    return float(counts.max() / mean) if mean else 1.0
+
+
 def survivor_batch(chunks_np, keep_np, pad_multiple):
     """Host-side ("master") re-batching of survivors for the MMSE phase:
     pad survivor count up to a multiple of the device count so the phase-B

@@ -137,3 +137,28 @@ def test_scoped_fused_tail_keeps_its_kernel_name(compile_for_v5e, monkeypatch):
     assert [re.sub(r"\.\d+$", "", c) for c, _ in calls] == ["fused_tail"]
     assert "/fused_tail/kernel/" in calls[0][1]
     assert re.search(r'op_name="jit\(tail_idx_fused\)/fused_tail/istft/', text)
+
+
+def test_four_chip_tail_gathers_once_in_its_scope(topo, compile_for_v5e,
+                                                  monkeypatch):
+    """The tail program of a batch split over the 2x2 host, as the plans
+    jit it: the batch is all-gathered to every chip once, by the explicit
+    collective under the `gather` scope, and nothing else crosses chips."""
+    import numpy as np
+    from jax.sharding import Mesh
+    from repro.distributed.sharding import ShardingRules
+    monkeypatch.setattr(backend, "resolve", lambda: (True, False))
+    rules = ShardingRules(Mesh(np.array(topo.devices).reshape(4, 1),
+                               ("data", "model")))
+    fn = Preprocessor(cfg, rules, plan="async",
+                      pad_multiple=4).phase_fn("tail_idx_fused")
+    w = jax.ShapeDtypeStruct((96, S5), F32,
+                             sharding=rules.sharding("chunks", None))
+    i = jax.ShapeDtypeStruct((16,), I32, sharding=rules.sharding("chunks"))
+    text = jax.jit(fn).lower(w, i).compile().as_text()
+    colls = re.findall(r"^\s*%\S+ = \S+ (all-gather|all-reduce|all-to-all|"
+                       r"collective-permute|reduce-scatter)(?:-start)?\("
+                       r".*op_name=\"([^\"]*)\"", text, re.M)
+    assert [c for c, _ in colls] == ["all-gather"], colls
+    assert colls[0][1].startswith("jit(tail_idx_fused)/fused_tail/")
+    assert "/gather/" in colls[0][1]
