@@ -152,7 +152,10 @@ are off. What each plan emits and where it lands:
     `mask_wait`, `compact`, `tail_enqueue`, `emit` (the emitting loop's
     wait for the cleaned rows), `tail_wait` and `d2h_wait` (the readback,
     on `AsyncPlan`'s reader thread while input flows), `fused_batch`; the
-    `_s` timings are these spans' durations (`obs_tracing.timed`);
+    `_s` timings are these spans' durations (`obs_tracing.timed`). A
+    cleaned batch split by rows over a mesh is read shard by shard on the
+    plan's shard threads, each in a `d2h_shard` span (also carrying
+    `shard`, its place in row order) around its `d2h_shard_copy`;
     ShardedPlan's proc master additionally marks `accept` (result
     accepted at the completion gate) and `emit_gated` instants, whose
     gap makes straggler-blocked emission visible. Worker processes
@@ -212,7 +215,7 @@ import operator
 import os
 import threading
 import time
-from concurrent.futures import ThreadPoolExecutor
+from concurrent.futures import ThreadPoolExecutor, wait
 from dataclasses import dataclass, field, replace
 
 import jax
@@ -353,8 +356,10 @@ class BatchResult:
     #   tail_wait_s tail_wait: until the tail's output exists on device
     #   d2h_wait_s  d2h_wait: the rest of the copy back and its host
     #               re-layout (both on whichever thread reads back)
+    #   d2h_shards  shards of a row-split cleaned batch read apart, in
+    #               parallel (0: read whole, as on one device)
     #   emit_ready  AsyncPlan: 1.0 when the rows were on the host already
-    #               as the loop took the batch, else 0.0 (these three only
+    #               as the loop took the batch, else 0.0 (these four only
     #               for batches that ran a tail)
     #   in_flight   detect batches in the window when this one dispatched
     #   d2h_bytes / h2d_bytes   host-boundary traffic this batch caused
@@ -482,6 +487,34 @@ class FusedPlan(ExecutionPlan):
         return res
 
 
+def _shard_reads(indices, shape, n_real):
+    """How to read the first `n_real` rows of an array split by rows, one
+    shard at a time. `indices` are its shards' `shard.index`, in any order.
+    Returns `(i, part, lo, hi)` for each shard that holds real rows: its
+    position `i` in `indices`, its place `part` in row order, and the real
+    rows [lo, hi) it holds. None unless the indices slice axis 0 alone
+    (every other axis whole) into two or more ranges that tile it once."""
+    spans = []
+    for i, (rows, *rest) in enumerate(indices):
+        if not isinstance(rows, slice) or rows.step not in (None, 1) or any(
+                not isinstance(r, slice) or r.indices(d) != (0, d, 1)
+                for r, d in zip(rest, shape[1:])):
+            return None
+        lo, hi, _ = rows.indices(shape[0])
+        spans.append((lo, hi, i))
+    spans.sort()
+    edges = [0] + [hi for _, hi, _ in spans]
+    if len(spans) < 2 or edges[-1] != shape[0] \
+            or [lo for lo, _, _ in spans] != edges[:-1]:
+        return None
+    return [(i, part, lo, min(hi, n_real))
+            for part, (lo, hi, i) in enumerate(spans) if lo < n_real]
+
+
+# name prefix of the threads that read a row-split cleaned batch's shards
+SHARD_THREAD = "repro-shard"
+
+
 @dataclass
 class _PendingTail:
     """A batch whose tail is dispatched but not yet read back: everything
@@ -524,6 +557,11 @@ class TwoPhasePlan(ExecutionPlan):
                 f"{graph.names[graph._cut():]} are not the canonical "
                 f"[hpf ->] mmse fused tail")
         self.fuse_tail = bool(fuse_tail)
+        # threads reading a row-split cleaned batch's shards: started at
+        # the first such readback, one a shard; `AsyncPlan.run` ends them
+        # as it ends, else they stay with the plan (idle) until it goes
+        self._shard_pool = None
+        self._shard_pool_lock = threading.Lock()
 
     def detect(self, audio) -> PipelineOutput:
         return _jitted("detect", self.graph, self.rules)(jnp.asarray(audio))
@@ -579,16 +617,56 @@ class TwoPhasePlan(ExecutionPlan):
         from the fill gather, never repeats of real audio. The wait splits
         in two: the tail program finishing on the device, then the rest of
         the copy back (started at dispatch) and its host-side re-layout.
-        Touches only `pend`, so it may run on a thread of its own."""
+        Touches only `pend` and the plan's shard threads, so it may run on
+        a thread of its own."""
         if pend.out is None:
             return np.zeros((0, pend.det.wave5.shape[-1]), np.float32)
-        t, wid = pend.timings, pend.wid
+        out, n_real, t, wid = pend.out, pend.n_real, pend.timings, pend.wid
         with obs_tracing.timed("tail_wait", t, "tail_wait_s", wid=wid):
-            pend.out.block_until_ready()
+            out.block_until_ready()
         with obs_tracing.timed("d2h_wait", t, "d2h_wait_s", wid=wid):
-            cleaned = np.asarray(pend.out)[:pend.n_real]
-        t["d2h_bytes"] += pend.out.nbytes
+            shards = () if out.is_fully_replicated else out.addressable_shards
+            reads = _shard_reads([s.index for s in shards], out.shape, n_real)
+            if reads is None:
+                cleaned = np.asarray(out)[:n_real]
+            else:
+                cleaned = self._read_shards(pend, shards, reads)
+        t["d2h_shards"] = 0 if reads is None else len(reads)
+        t["d2h_bytes"] += out.nbytes
         return cleaned
+
+    def _read_shards(self, pend, shards, reads) -> np.ndarray:
+        """The real rows of a row-split cleaned batch, each shard's waited
+        for and copied into a fresh array on a thread of its own. JAX's
+        `np.asarray` of the whole array waits for the shards one after
+        another on the calling thread, and copies each into a fresh array
+        of every row, padding included. Fresh each batch: callers keep
+        `cleaned`."""
+        out, wid = pend.out, pend.wid
+        cleaned = np.empty((pend.n_real,) + out.shape[1:], out.dtype)
+
+        def read(i, part, lo, hi):
+            with obs_tracing.span("d2h_shard", wid=wid, shard=part):
+                rows = np.asarray(shards[i].data)   # its copy already started
+                with obs_tracing.span("d2h_shard_copy", wid=wid, shard=part):
+                    cleaned[lo:hi] = rows[:hi - lo]
+
+        with self._shard_pool_lock:
+            if self._shard_pool is None:
+                self._shard_pool = ThreadPoolExecutor(
+                    len(shards), thread_name_prefix=SHARD_THREAD)
+            futs = [self._shard_pool.submit(read, *r) for r in reads]
+        wait(futs)
+        for f in futs:
+            f.result()                  # a failed shard fails the batch
+        return cleaned
+
+    def _close_shard_pool(self):
+        """End the shard threads (after the last readback that uses them)."""
+        with self._shard_pool_lock:
+            pool, self._shard_pool = self._shard_pool, None
+        if pool is not None:
+            pool.shutdown(cancel_futures=True)
 
     def _emit(self, pend: _PendingTail, cleaned) -> BatchResult:
         """The batch's result, mirrored into the metrics registry."""
@@ -719,6 +797,7 @@ class AsyncPlan(TwoPhasePlan):
         finally:
             if reader is not None:       # early stop or error: no thread
                 reader.shutdown(cancel_futures=True)   # left behind
+            self._close_shard_pool()     # after the reader: it submits there
 
 
 class StreamingPlan(AsyncPlan):

@@ -7,6 +7,7 @@ import dataclasses
 import threading
 import time
 import warnings
+from types import SimpleNamespace
 
 import jax.numpy as jnp
 import numpy as np
@@ -14,8 +15,8 @@ import pytest
 
 from repro.configs import SERF_AUDIO as cfg
 from repro.core import scheduler as SCHED
-from repro.core.plans import (JIT_CACHE, PLANS, READER_THREAD, AsyncPlan,
-                              Preprocessor)
+from repro.core.plans import (JIT_CACHE, PLANS, READER_THREAD, SHARD_THREAD,
+                              AsyncPlan, Preprocessor)
 from repro.data.loader import audio_batch_maker
 from repro.data.synthetic import generate_labelled
 from repro.obs import metrics as obs_metrics
@@ -357,7 +358,83 @@ def test_no_reader_thread_outlives_run(monkeypatch, stop):
     else:
         with pytest.raises(_Boom, match="batch 3"):
             next(it)
-    assert not _readers()
+    assert not _readers() and not _shard_threads()
+
+
+def _shard_threads():
+    return [t for t in threading.enumerate()
+            if t.name.startswith(SHARD_THREAD)]
+
+
+class _RowSplit:
+    """A tail's output as a mesh of four holds it, split by rows: what the
+    plan reads back shard by shard. With `fail`, reading the third shard's
+    rows raises."""
+    is_fully_replicated = False
+
+    def __init__(self, out, fail):
+        self._out = out
+        self.shape, self.dtype, self.nbytes = out.shape, out.dtype, out.nbytes
+        n = out.shape[0] // 4
+        self.addressable_shards = [
+            SimpleNamespace(index=(slice(lo, lo + n), slice(None)),
+                            data=_Rows(out, lo, lo + n, fail and lo == 2 * n))
+            for lo in range(0, out.shape[0], n)]
+
+    def block_until_ready(self):
+        return self._out.block_until_ready()
+
+
+class _Rows:
+    """One shard's data: its rows of the whole output, on the host."""
+
+    def __init__(self, out, lo, hi, fail):
+        self.out, self.lo, self.hi, self.fail = out, lo, hi, fail
+
+    def __array__(self, dtype=None, copy=None):
+        if self.fail:
+            raise _Boom("a shard's rows of batch 4")
+        return np.asarray(self.out)[self.lo:self.hi]
+
+
+@pytest.mark.parametrize("stop", ["break", "error", "shard_error"])
+def test_no_shard_thread_outlives_run(monkeypatch, stop):
+    """With outputs split by rows, each readback runs on the shard threads
+    too; stopping early, an error inside a readback, or one inside a
+    shard's read leaves none of them (and no reader) alive, and a shard's
+    error surfaces from the next() for its batch."""
+    pre = Preprocessor(cfg, plan="async", depth=1, pad_multiple=4)
+    plan = pre.plan
+    start_tail, readback = plan._start_tail, plan._readback
+    bad = 4 if stop == "shard_error" else 3     # seed 28: batch 3 cut whole
+
+    def split_start_tail(*a):
+        pend = start_tail(*a)
+        if pend.out is not None:
+            pend.out = _RowSplit(pend.out, stop == "shard_error"
+                                 and pend.wid == bad)
+        return pend
+
+    def failing_readback(pend):
+        if pend.wid == bad:
+            raise _Boom(f"readback of batch {bad}")
+        return readback(pend)
+
+    monkeypatch.setattr(plan, "_start_tail", split_start_tail)
+    if stop == "error":
+        monkeypatch.setattr(plan, "_readback", failing_readback)
+    it = pre.run(_stream(28, 8))
+    got = [next(it) for _ in range(bad)]
+    assert [r.wid for r in got] == list(range(bad))
+    assert _readers() and _shard_threads()
+    for r in got:
+        assert r.timings.get("d2h_shards", 0) == (4 if r.n_kept else 0)
+    if stop == "break":
+        it.close()
+    else:
+        with pytest.raises(_Boom, match=f"batch {bad}"):
+            next(it)
+    assert not _readers() and not _shard_threads()
 
 
 @pytest.mark.parametrize("plan, n", [("async", 1), ("streaming", 8)])

@@ -98,6 +98,9 @@ for (_, xb), a, b in zip(stream, sh, one, strict=True):
     counts = keep.reshape(4, -1).sum(1)
     assert t["kept_skew"] == counts.max() / counts.mean()
     assert b.timings["gather_bytes"] == 0 and b.timings["kept_skew"] == 1.0
+    # the cleaned rows come back shard by shard where a shard holds any
+    assert t["d2h_shards"] == -(-a.n_kept // (t["tail_rows"] // 4)) > 1
+    assert b.timings["d2h_shards"] == 0
 snap = obs_metrics.snapshot()["plan_gather_bytes_total"]["series"]
 assert snap[0]["value"] == sum(a.timings["gather_bytes"] for a in sh)
 
