@@ -1,77 +1,10 @@
 #!/usr/bin/env bash
-# Tier-1 verification gate (see ROADMAP.md "Tier-1 verify"):
-#   1. the repo's own test suite
-#   2. the executor smoke: one tiny batch through every registered
-#      execution plan (survivor sets must agree bit-for-bit), PLUS the
-#      sharded fault-tolerance gate — ShardedPlan over 2 simulated shards
-#      with a forced lease expiry and a mid-stream worker crash must
-#      finish with redeliveries >= 1 and zero lost/duplicated chunks —
-#      PLUS the process-mode FT gate — the same recovery on 2 REAL worker
-#      processes over the repro.dist proc transport, one SIGKILLed
-#      mid-stream while holding a lease: zero lost/duplicate chunks,
-#      output bit-identical to two_phase —
-#      PLUS the store-data-plane gate — the same stream over 2 REAL
-#      worker processes on the TCP transport twice, socket data plane vs
-#      store data plane (chunk batches and result payloads through a
-#      shared ChunkStore, the socket carrying only content keys): the
-#      store run must cut the master's data-plane socket bytes by >= 90%
-#      (measured from dist_fetch_bytes_total{plane} +
-#      dist_push_bytes_total{plane}) while staying bit-identical —
-#      PLUS the cache gate — the same tiny stream twice through
-#      CachedPlan over a fresh store: the second pass must be >= 90%
-#      cache hits with survivor masks bit-identical to the uncached plan —
-#      PLUS the async-pipeline gate — `--plan async --depth 4` on a tiny
-#      stream must emit every chunk id exactly once in input order,
-#      bit-identical to two_phase, with >= 1 overlapped dispatch observed
-#      in the per-batch timing records —
-#      PLUS the serving gate — a persistent pool of 2 proc workers behind
-#      the continuous batcher serving 12 concurrent requests, one with an
-#      already-expired deadline (must fail, never dispatch) and one
-#      worker SIGKILLed at its first lease grant (work redelivered): all
-#      surviving requests answered exactly once, bit-identical to
-#      two_phase —
-#      PLUS the fused-tail gate — two_phase with the fused single-pass
-#      survivor tail (gather+hpf+stft+mmse in one kernel) vs the staged
-#      per-stage tail: masks + cleaned audio bit-identical in both the
-#      ref and interpret backends, pad-index rows exactly zero —
-#      PLUS the observability gate — the launch driver over 2 REAL proc
-#      workers with --trace + --telemetry: the Chrome trace must pass
-#      the repro.obs schema check (required keys, known phases, X events
-#      carry dur, B/E balance LIFO per pid/tid) with worker-process
-#      events parented under the master's run span across the pickle
-#      boundary, and the durable telemetry JSONL must hold exactly ONE
-#      master-side 'done' record per chunk —
-#      PLUS the chaos gate — seeded randomized schedules (>= 1 SIGKILL,
-#      >= 1 mid-run join, >= 1 graceful drain, >= 1 SIGSTOP stall each)
-#      fired against 2+ REAL proc workers while the stream runs: every
-#      chunk exactly once, masks AND cleaned audio bit-identical to
-#      two_phase, redeliveries and registered late joiners observed;
-#      then the injected-straggler scenario — the last chunk's holder is
-#      SIGSTOPped at grant, an idle survivor must win the speculative
-#      duplicate lease, and the losing incarnation must be attributed in
-#      durable telemetry under reason "speculated". A failing schedule
-#      prints its seed; reproduce with
-#        bash scripts/verify.sh --chaos-seed <seed>
-#      (forwarded to `benchmarks.run --smoke`, which then runs ONLY that
-#      schedule plus the speculation scenario)
+# Tier-1 verification (see ROADMAP.md "Tier-1 verify"): the repo's own
+# test suite. Extra arguments go to pytest.
 #
-#   bash scripts/verify.sh [--chaos-seed N] [extra pytest args]
+#   bash scripts/verify.sh [pytest args]
 set -euo pipefail
 cd "$(dirname "$0")/.."
 export PYTHONPATH=src${PYTHONPATH:+:$PYTHONPATH}
 
-CHAOS_ARGS=()
-PYTEST_ARGS=()
-while [[ $# -gt 0 ]]; do
-  case "$1" in
-    --chaos-seed)
-      CHAOS_ARGS=(--chaos-seed "$2"); shift 2 ;;
-    --chaos-seed=*)
-      CHAOS_ARGS=(--chaos-seed "${1#*=}"); shift ;;
-    *)
-      PYTEST_ARGS+=("$1"); shift ;;
-  esac
-done
-
-python -m pytest -x -q "${PYTEST_ARGS[@]+"${PYTEST_ARGS[@]}"}"
-python -m benchmarks.run --smoke "${CHAOS_ARGS[@]+"${CHAOS_ARGS[@]}"}"
+python -m pytest -x -q "$@"

@@ -8,10 +8,6 @@ paper exactly.
 """
 from __future__ import annotations
 
-import jax.numpy as jnp
-
-from repro.core import indices as I
-
 
 def detect_rain(idx, cfg):
     """Heavy rain: high broadband power, flat spectrum, flat envelope."""
@@ -42,14 +38,3 @@ def detect_no_activity(idx, cfg, threshold=None):
     silence threshold."""
     thr = cfg.flux_threshold if threshold is None else threshold
     return idx["flux"] < thr
-
-
-def classify_chunks(power, cfg):
-    """Full detector pass over chunk power spectra: (B,F,K) -> dict of (B,)
-    masks + the index vector (for benchmarks)."""
-    idx = I.all_indices(power, cfg)
-    rain = detect_rain(idx, cfg)
-    cicada = detect_cicada(idx, cfg) & ~rain
-    silence = detect_silence(idx, cfg) & ~rain
-    return {"rain": rain, "cicada": cicada, "silence": silence,
-            "indices": idx}

@@ -90,8 +90,7 @@ def spectral_flux(power):
 
 
 def all_indices(power, cfg):
-    """The index vector used by the rule classifiers (and exported for the
-    benchmark reproducing the paper's classifier-feature table)."""
+    """The index vector used by the rule classifiers."""
     pk, peak_bin = band_peakiness(power, *cfg.cicada_band_hz,
                                   cfg.stft_window, cfg.target_rate_hz)
     return {

@@ -72,8 +72,8 @@ the execution strategy. Six plans, and when to pick each:
                       batches from and push results into the shared
                       store, and the master materialises payloads by key
                       at acceptance — fetch/push socket traffic drops
-                      ≥90% per chunk (graded by the smoke gate via
-                      `dist_fetch_bytes_total{plane}`). Works under
+                      ≥90% per chunk (`dist_fetch_bytes_total{plane}`,
+                      checked by `tests/test_dist_dataplane.py`). Works under
                       "proc" too; it is what makes "tcp" scale past one
                       box. Workers lease work ids in batches (`lease_items`,
                       the paper's Table 7 `max_queue_size` knob —

@@ -13,8 +13,7 @@ scheduling, not the payload):
     expire and the queue redelivers — at-least-once, no crash-tracking
     master. Its `lease_items` is the paper's Table 7 `max_queue_size` knob:
     ids leased per round-trip — the same knob real worker processes
-    (`repro.dist.worker --lease-items`) sweep, and
-    `benchmarks/bench_queue_depth.py` measures.
+    take as `repro.dist.worker --lease-items`.
 
 Prefetch depth == the paper's slave queue size (Table 7 sweeps it). The
 cursor (next work id + RNG seed) rides in checkpoint meta for exact resume.

@@ -1,7 +1,7 @@
 """Synthetic SERF-like labelled audio.
 
-SERF recordings are not redistributable, so benchmarks and detector
-calibration use a seeded generator that reproduces the paper's noise
+SERF recordings are not redistributable, so the tests, `launch.preprocess`
+and detector calibration use a seeded generator that reproduces the paper's noise
 taxonomy: bird chirps (FM sweeps 2-8 kHz, transient), heavy rain (loud
 broadband noise), cicada chorus (sustained narrowband noise 3.5-7 kHz, AM),
 silence (low-level background), over stereo 44.1 kHz audio with ground-truth
@@ -106,9 +106,3 @@ def generate_labelled(seed, n_segments, segment_s=5.0, rate=44_100,
         audio.append(x)
         labels.append(li)
     return np.stack(audio), np.asarray(labels, np.int32)
-
-
-def generate_hours(seed, hours, rate=44_100, **kw):
-    """Convenience: enough 5 s segments to cover `hours` of audio."""
-    n = int(hours * 3600 / 5.0)
-    return generate_labelled(seed, n, 5.0, rate, **kw)

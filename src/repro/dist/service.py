@@ -427,8 +427,8 @@ class QueueService:
     def _note_fetch(self, wid, item, plane, key=None):
         """Per-item data-plane accounting: the socket plane is charged
         the batch's bytes, the store plane only the key that replaced
-        them — `dist_fetch_bytes_total{plane}` is how the smoke gate
-        proves the ≥90% cut."""
+        them — `dist_fetch_bytes_total{plane}` is how the tests prove
+        the ≥90% cut."""
         raw = np.ascontiguousarray(item)
         wire = len(key) if plane == "store" else int(raw.nbytes)
         obs_metrics.counter(

@@ -322,7 +322,7 @@ class TcpTransport(ProcTransport):
     wire protocol, authkey handshake, and worker runtime stay identical.
     `advertise_host` is the address workers are told to dial — it
     defaults to loopback for the wildcard bind (the single-box case the
-    tests and smoke gates run); set it to the master's routable address
+    tests run); set it to the master's routable address
     when the fleet spans machines. Pair with `StoreDataPlane` over a
     shared directory so chunk bytes never transit this socket."""
     name = "tcp"

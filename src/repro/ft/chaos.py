@@ -17,8 +17,8 @@ victim holding work is what exercises redelivery and speculation; if no
 preferred target exists the event defers briefly, then fires anyway.
 
 Safety guards, not mercy: an event that would leave ZERO active workers
-(killing or draining the last one) spawns a replacement first — the gate
-is testing elasticity, not the obvious theorem that an empty fleet makes
+(killing or draining the last one) spawns a replacement first — a schedule
+tests elasticity, not the obvious theorem that an empty fleet makes
 no progress. Everything else is fair game, and the acceptance bar is
 absolute: every chunk exactly once, bit-identical to `two_phase`.
 """

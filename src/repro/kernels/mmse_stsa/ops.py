@@ -24,11 +24,3 @@ def mmse_gain(power, noise_psd, alpha=0.98, gain_floor=0.1):
     g = K.mmse_gain_pallas(power, noise_psd, alpha, gain_floor,
                            interpret=interp)
     return g[..., :Kbins]
-
-
-def denoise_spectrum(spec, alpha=0.98, gain_floor=0.1, noise_frames=16):
-    """spec: complex (B,F,K) STFT -> gain-filtered complex spectrum."""
-    power = jnp.real(spec) ** 2 + jnp.imag(spec) ** 2
-    noise = R.estimate_noise_psd(power, noise_frames)
-    g = mmse_gain(power, noise, alpha, gain_floor)
-    return spec * g.astype(spec.dtype)

@@ -37,20 +37,6 @@ def stft(x, window=256, hop=128):
     return jax.lax.complex(packed[..., :bins], packed[..., bins:2 * bins])
 
 
-def stft_power(x, window=256, hop=128):
-    """x: (B,S) -> power spectrum (B,F,bins) f32."""
-    use_pallas, interp = backend.resolve()
-    if backend.matmul_dft():
-        z = R.stft_matmul(x, window, hop)
-        return jnp.real(z) ** 2 + jnp.imag(z) ** 2
-    if not use_pallas:
-        return R.power_spectrum(x, window, hop)
-    bins = window // 2 + 1
-    packed = K.stft_pallas(x, window, hop, interpret=interp)
-    re, im = packed[..., :bins], packed[..., bins:2 * bins]
-    return re * re + im * im
-
-
 def istft(z, n_samples, window=256, hop=128):
     """Inverse STFT (overlap-add; matmul inverse-DFT under mode "matmul")."""
     if backend.matmul_dft():

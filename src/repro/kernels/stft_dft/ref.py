@@ -46,17 +46,6 @@ def stft_ref(x, window=256, hop=128):
     return jnp.fft.rfft(frames, axis=-1)
 
 
-def stft_ref_packed(x, window=256, hop=128):
-    """Packed real output (B, F, 2*(window//2+1)): [re | im]."""
-    z = stft_ref(x, window, hop)
-    return jnp.concatenate([jnp.real(z), jnp.imag(z)], axis=-1)
-
-
-def power_spectrum(x, window=256, hop=128):
-    z = stft_ref(x, window, hop)
-    return jnp.abs(z) ** 2
-
-
 # --------------------------------------------------------- matmul-DFT path
 # The TPU target computes the DFT as a matmul on the MXU (kernel.py). These
 # pure-jnp equivalents run the SAME computation shape without pallas — used

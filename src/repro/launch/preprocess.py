@@ -318,8 +318,7 @@ def worker_summary(worker_stats):
 
 def pipeline_report(timings):
     """Aggregate per-batch plan timing records into per-stage means, the
-    overlap count, and host-boundary traffic (shared by this driver and
-    benchmarks/bench_dispatch_depth.py)."""
+    overlap count, and host-boundary traffic."""
     n = len(timings)
     rep = {"batches": n}
     for k in ("dispatch", "readback", "compact", "tail", "emit"):

@@ -15,8 +15,7 @@ stands up a `WorkerPool` (long-lived `repro.dist` workers, warm jits
 across waves), fronts it with a `ContinuousBatcher` (pow2 zero-padded
 batch assembly, admission control, per-request deadlines), drives it
 with concurrent client threads, and reports p50/p99 latency, batch
-occupancy, and the per-worker ledger. `benchmarks/bench_serving.py` is
-the calibrated load-test version of the same loop.
+occupancy, and the per-worker ledger.
 """
 from __future__ import annotations
 

@@ -20,8 +20,9 @@ Three cooperating components, each usable alone:
     Chrome trace-event JSON that loads directly in Perfetto.
 
 Everything is zero-cost-when-off: the disabled registry and the null
-tracer are shared no-op objects, and `benchmarks/bench_obs_overhead.py`
-enforces <5% wall-clock impact when ON (with bit-identical outputs).
+tracer are shared no-op objects. With the plan's spans in its path, the
+archive pass on a TPU v5e read the same `audio_rate` as without them
+(PERF.md §6).
 """
 from repro.obs import metrics, telemetry, tracing
 from repro.obs.metrics import MetricsRegistry, NullRegistry, get_registry, set_registry

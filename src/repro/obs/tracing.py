@@ -24,8 +24,8 @@ Event kinds used:
 
 `validate_chrome_trace` is the schema gate: every event must carry
 `ph`/`ts`/`pid`/`tid`/`name`, and `B`/`E` must balance LIFO per
-(pid, tid).  The smoke gate and tests call it so a Perfetto-breaking
-regression fails CI, not a human.
+(pid, tid).  The tests call it so a Perfetto-breaking regression fails
+CI, not a human.
 
 Spans also reach the profiler: while a `jax.profiler` session records,
 every `span()` opens a `jax.profiler.TraceAnnotation` named `repro.<name>`

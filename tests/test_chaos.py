@@ -1,19 +1,22 @@
 """Elastic-fleet primitives: straggler detection, speculative duplicate
 leases, the membership registry, seeded chaos schedules, graceful drain +
-late join through the real worker runtime, and pool autoscaling."""
+late join through the real worker runtime, pool autoscaling, and the
+fleet under fire: a seeded schedule and an injected end-of-stream
+straggler over real proc workers."""
 import threading
 import time
 
 import numpy as np
+import pytest
 
 from repro.configs import SERF_AUDIO as cfg
 from repro.core.plans import Preprocessor
-from repro.data.loader import audio_batch_maker
+from repro.data.loader import audio_batch_maker, make_shard_pool
 from repro.data.queue import SettableClock, WorkQueue
 from repro.dist.service import QueueService
 from repro.dist.transport import InProcTransport
 from repro.dist.worker import run_worker
-from repro.ft.chaos import ACTIONS, make_schedule
+from repro.ft.chaos import ACTIONS, ChaosRunner, make_schedule
 from repro.ft.failure import StragglerDetector
 
 
@@ -357,3 +360,99 @@ def test_worker_pool_autoscale_inproc():
             np.testing.assert_array_equal(got[wid].cleaned, want.cleaned)
     finally:
         pool.shutdown(drain=False)
+
+
+# ------------------------------------------- real proc workers under fire
+
+def _assert_matches_two_phase(results, make):
+    ref = Preprocessor(cfg, plan="two_phase", pad_multiple=1)
+    for r in results:
+        want = ref(make(r.wid)[0])
+        np.testing.assert_array_equal(np.asarray(r.det.keep),
+                                      np.asarray(want.det.keep))
+        np.testing.assert_array_equal(r.cleaned, want.cleaned)
+
+
+@pytest.mark.parametrize("seed", [11])
+def test_chaos_schedule_over_proc_workers(seed):
+    """A seeded schedule (at least one SIGKILL, mid-run join, graceful
+    drain and SIGSTOP stall) fired at 2 real proc workers while the
+    stream runs: every event fires, every chunk comes out exactly once,
+    masks and cleaned audio bit-identical to two_phase, a lease is
+    redelivered and the late joiner registers. A failing schedule
+    reproduces on its own with `-k <seed>`."""
+    n_batches = 6
+    make = audio_batch_maker(seed=seed, batch_long_chunks=1)
+    pool = make_shard_pool(make, n_batches, 2, lease_timeout_s=300.0)
+    pre = Preprocessor(cfg, plan="sharded", shards=2, pad_multiple=1,
+                       transport="proc", elastic=True)
+    schedule = make_schedule(seed, n_batches)
+    results, fired = ChaosRunner(pre.plan, pool, schedule, seed=seed).run()
+    assert sorted(r.wid for r in results) == list(range(n_batches))
+    assert [e.action for e in schedule if not e.fired] == []
+    by_action = {a: sum(e.action == a for e in fired) for a in ACTIONS}
+    assert all(n >= 1 for n in by_action.values()), by_action
+    _assert_matches_two_phase(results, make)
+    assert pre.plan.redeliveries >= 1
+    names = {st.worker for st in pre.plan.worker_stats}
+    assert any(e.action == "join" and f"shard{e.target}" in names
+               for e in fired), "no late joiner registered"
+
+
+def test_speculation_over_proc_workers_attributes_the_loser(tmp_path):
+    """The last chunk's holder is SIGSTOPped at grant: with a factor-0
+    straggler detector the idle worker wins a speculative duplicate
+    lease, every chunk comes out exactly once and bit-identical to
+    two_phase, and durable telemetry attributes the losing incarnation
+    under reason "speculated" with one 'done' record per chunk."""
+    from repro.obs.telemetry import (TelemetryWriter, read_records,
+                                     worker_ledger)
+
+    n_batches = 6
+    make = audio_batch_maker(seed=7, batch_long_chunks=1)
+    pool = make_shard_pool(make, n_batches, 2, lease_timeout_s=300.0)
+    telem = TelemetryWriter(tmp_path)
+    pre = Preprocessor(cfg, plan="sharded", shards=2, pad_multiple=1,
+                       transport="proc", speculate=True,
+                       straggler_factor=0.0, straggler_min_history=1,
+                       telemetry=telem)
+    plan = pre.plan
+    results, err, stalled = [], [], []
+
+    def consume():
+        try:
+            results.extend(plan.run(pool))
+        except BaseException as e:      # noqa: BLE001 — reraised below
+            err.append(e)
+
+    def on_grant(worker, wid):
+        # stopped far longer than the idle survivor needs for the copy
+        if wid == n_batches - 1 and not stalled:
+            stalled.append(worker)
+            plan.fleet.stall(plan.fleet.service.workers[worker].shard,
+                             20.0)
+
+    t = threading.Thread(target=consume, daemon=True)
+    t.start()
+    try:
+        while plan.fleet is None and t.is_alive():
+            time.sleep(0.01)
+        if plan.fleet is not None:
+            plan.fleet.service.on_grant = on_grant
+        t.join(300.0)
+        assert not t.is_alive(), "speculation run hung"
+    finally:
+        telem.close()
+    if err:
+        raise err[0]
+    assert sorted(r.wid for r in results) == list(range(n_batches))
+    _assert_matches_two_phase(results, make)
+    assert stalled, "the last chunk was never granted"
+    assert plan.speculations >= 1
+    assert plan.speculations_lost >= 1
+    recs = read_records(str(tmp_path))
+    assert any(r.get("status") == "redelivered"
+               and r.get("reason") == "speculated" for r in recs)
+    assert any(e["speculation_lost"] for e in worker_ledger(recs).values())
+    assert sorted(r["wid"] for r in recs
+                  if r.get("status") == "done") == list(range(n_batches))

@@ -313,7 +313,8 @@ def test_proc_sigkill_leaves_redelivery_attribution(tmp_path):
     """A worker SIGKILLed while holding a lease must leave a durable
     'redelivered' record attributing the LOSING incarnation, and the
     eventual 'done' record must carry the redelivery count and the
-    surviving worker — both attempts visible in one ledger."""
+    surviving worker — both attempts visible in one ledger. The results
+    still come out once each, in input order."""
     from repro.data.loader import audio_batch_maker, make_shard_pool
     from repro.ft.failure import CrashInjector
 
@@ -328,8 +329,9 @@ def test_proc_sigkill_leaves_redelivery_attribution(tmp_path):
                            transport="proc", injector=injector,
                            telemetry=w)
         results = list(pre.run(pool))
-    assert sorted(r.wid for r in results) == list(range(n_batches))
+    assert [r.wid for r in results] == list(range(n_batches))
     assert pre.plan.redeliveries >= 1
+    assert injector.crashed == frozenset({1})
 
     recs = obs_telemetry.read_records(str(d))
     done = {r["wid"]: r for r in recs if r["status"] == "done"}
@@ -344,6 +346,52 @@ def test_proc_sigkill_leaves_redelivery_attribution(tmp_path):
     led = obs_telemetry.worker_ledger(recs)
     assert led["shard1"]["redelivered_from"] >= 1
     assert led["shard0"]["chunks_done"] == n_batches
+
+
+def test_driver_over_proc_workers_traces_and_telemetry(tmp_path):
+    """The driver (`launch.preprocess`) over 2 real proc workers with
+    `--trace` and `--telemetry`: the Chrome trace validates, one run span
+    opens in the master, every worker-process event carries the master's
+    trace id and (openers and completes) the run span as parent across
+    the pickle boundary, and the durable telemetry holds exactly one
+    master-side 'done' record per chunk with its accept timestamp."""
+    from repro.launch import preprocess as launch_pre
+
+    n_batches = 2                      # --minutes 4 / --batch-long-chunks 2
+    trace_path = tmp_path / "trace.json"
+    tdir = tmp_path / "telemetry"
+    prev_tracer = obs_tracing.get_tracer()
+    prev_cache = jax.config.jax_compilation_cache_dir
+    try:
+        launch_pre.main([
+            "--minutes", "4", "--batch-long-chunks", "2",
+            "--plan", "sharded", "--transport", "proc", "--shards", "2",
+            "--trace", str(trace_path), "--telemetry", str(tdir)])
+    finally:
+        obs_tracing.set_tracer(prev_tracer)
+        jax.config.update("jax_compilation_cache_dir", prev_cache)
+    data = json.loads(trace_path.read_text())
+    validate_chrome_trace(data)
+    events = data["traceEvents"]
+    trace_id = data["otherData"]["trace_id"]
+    run_span = trace_id + ":0"
+    roots = [e for e in events
+             if e["name"] == "preprocess_run" and e["ph"] == "B"]
+    assert len(roots) == 1
+    assert roots[0]["pid"] == os.getpid()
+    assert roots[0]["args"]["span"] == run_span
+    worker_evs = [e for e in events if e["pid"] != os.getpid()]
+    assert worker_evs, "no worker-process events reached the trace"
+    assert all(e["args"].get("trace") == trace_id for e in worker_evs)
+    # 'E' closers carry no parent by design
+    assert all(e["args"].get("parent") == run_span
+               for e in worker_evs if e["ph"] != "E")
+    assert any(e["name"] == "compute" for e in worker_evs)
+
+    done = [r for r in obs_telemetry.read_records(str(tdir))
+            if r.get("status") == "done"]
+    assert sorted(r["wid"] for r in done) == list(range(n_batches))
+    assert all(r.get("accept_ts") and r.get("worker") for r in done)
 
 
 # ---------------------------------------------------------- ring caps

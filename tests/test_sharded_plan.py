@@ -169,7 +169,8 @@ def test_sharded_rebalance_ratio_on_skewed_stream():
 
 def test_sharded_crash_recovery_exactly_once():
     """Acceptance: a killed worker mid-stream finishes the run with
-    redeliveries >= 1 and no missing or duplicate chunk ids."""
+    redeliveries >= 1, no missing or duplicate chunk ids, and the
+    two_phase masks and cleaned audio."""
     n_batches = 6
     make = audio_batch_maker(seed=2, batch_long_chunks=1)
     pool = make_shard_pool(make, n_batches, 3)
@@ -187,6 +188,8 @@ def test_sharded_crash_recovery_exactly_once():
         want = ref(make(r.wid)[0])
         np.testing.assert_array_equal(np.asarray(r.det.keep),
                                       np.asarray(want.det.keep))
+        np.testing.assert_allclose(r.cleaned, want.cleaned,
+                                   rtol=1e-4, atol=1e-5)
 
 
 def test_sharded_forced_lease_expiry_redelivers():

@@ -139,9 +139,11 @@ def test_two_phase_requires_removal_point():
 @pytest.mark.parametrize("pad_multiple", [1, 2, 8])
 def test_plan_equivalence(chunks, pad_multiple):
     """fused == two_phase == streaming on the survivor set, for every
-    phase-B pad multiple (padding must never leak into results)."""
+    phase-B pad multiple (padding must never leak into results); the
+    fused reference is finite, so a NaN in any plan fails the match."""
     x = jnp.asarray(chunks)
     ref = Preprocessor(cfg, plan="fused")(x)
+    assert np.isfinite(ref.cleaned).all()
     two = Preprocessor(cfg, plan="two_phase", pad_multiple=pad_multiple)(x)
     np.testing.assert_array_equal(np.asarray(two.det.keep),
                                   np.asarray(ref.det.keep))
