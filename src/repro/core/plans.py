@@ -155,7 +155,10 @@ are off. What each plan emits and where it lands:
     `_s` timings are these spans' durations (`obs_tracing.timed`). A
     cleaned batch split by rows over a mesh is read shard by shard on the
     plan's shard threads, each in a `d2h_shard` span (also carrying
-    `shard`, its place in row order) around its `d2h_shard_copy`;
+    `shard`, its place in row order) around the wait for its host value
+    and, where that is in row order, its `d2h_shard_copy`; where every
+    shard's host value is in column order the copy is split by column
+    blocks instead, one `d2h_block_copy` span (carrying `block`) each;
     ShardedPlan's proc master additionally marks `accept` (result
     accepted at the completion gate) and `emit_gated` instants, whose
     gap makes straggler-blocked emission visible. Worker processes
@@ -358,6 +361,8 @@ class BatchResult:
     #               re-layout (both on whichever thread reads back)
     #   d2h_shards  shards of a row-split cleaned batch read apart, in
     #               parallel (0: read whole, as on one device)
+    #   d2h_col_blocks  column blocks the copy of those shards was split
+    #               into (0: copied shard by shard, or read whole)
     #   emit_ready  AsyncPlan: 1.0 when the rows were on the host already
     #               as the loop took the batch, else 0.0 (these four only
     #               for batches that ran a tail)
@@ -414,7 +419,9 @@ def _record_batch(plan_name, res: "BatchResult"):
     for k, what in (("d2h_bytes", "host-boundary traffic"),
                     ("h2d_bytes", "host-boundary traffic"),
                     ("gather_bytes", "bytes each device received in the "
-                     "tail's all-gather")):
+                     "tail's all-gather"),
+                    ("d2h_col_blocks", "column blocks the cleaned "
+                     "readback's copy was split into")):
         if k in t:
             reg.counter(f"plan_{k}_total", what,
                         ("plan",)).labels(**lab).inc(int(t[k]))
@@ -628,38 +635,74 @@ class TwoPhasePlan(ExecutionPlan):
             shards = () if out.is_fully_replicated else out.addressable_shards
             reads = _shard_reads([s.index for s in shards], out.shape, n_real)
             if reads is None:
-                cleaned = np.asarray(out)[:n_real]
+                cleaned, blocks = np.asarray(out)[:n_real], 0
             else:
-                cleaned = self._read_shards(pend, shards, reads)
+                cleaned, blocks = self._read_shards(pend, shards, reads)
         t["d2h_shards"] = 0 if reads is None else len(reads)
+        t["d2h_col_blocks"] = blocks
         t["d2h_bytes"] += out.nbytes
         return cleaned
 
-    def _read_shards(self, pend, shards, reads) -> np.ndarray:
-        """The real rows of a row-split cleaned batch, each shard's waited
-        for and copied into a fresh array on a thread of its own. JAX's
-        `np.asarray` of the whole array waits for the shards one after
-        another on the calling thread, and copies each into a fresh array
-        of every row, padding included. Fresh each batch: callers keep
-        `cleaned`."""
+    def _read_shards(self, pend, shards, reads):
+        """The real rows of a row-split cleaned batch as a fresh array
+        (callers keep `cleaned`), and the column blocks its copy was split
+        into. Each shard's host value is waited for on a thread of its own:
+        JAX's `np.asarray` of the whole array waits for the shards one
+        after another on the calling thread, and copies each into a fresh
+        array of every row, padding included.
+
+        A host value in row order is copied into a C-order result at once,
+        on its shard's thread. Where every one is in column order (a TPU
+        runtime's), the result takes that order and the copy is split by
+        column blocks, one a shard thread: each thread then writes
+        contiguous runs into pages of its own, where a row copy would
+        transpose."""
         out, wid = pend.out, pend.wid
-        cleaned = np.empty((pend.n_real,) + out.shape[1:], out.dtype)
+        shape = (pend.n_real,) + out.shape[1:]
+        # untouched, so never faulted in, when the column path takes over
+        cleaned = np.empty(shape, out.dtype)
 
-        def read(i, part, lo, hi):
-            with obs_tracing.span("d2h_shard", wid=wid, shard=part):
-                rows = np.asarray(shards[i].data)   # its copy already started
-                with obs_tracing.span("d2h_shard_copy", wid=wid, shard=part):
-                    cleaned[lo:hi] = rows[:hi - lo]
+        def copy(r, rows):
+            _, part, lo, hi = r
+            with obs_tracing.span("d2h_shard_copy", wid=wid, shard=part):
+                cleaned[lo:hi] = rows[:hi - lo]
 
+        def read(r):
+            with obs_tracing.span("d2h_shard", wid=wid, shard=r[1]):
+                rows = np.asarray(shards[r[0]].data)   # its copy started
+                if np.isfortran(rows):
+                    return rows         # copied once every shard's is here
+                copy(r, rows)
+
+        n = len(shards)
+        held = [(r, rows) for r, rows in zip(
+            reads, self._on_shard_threads(read, reads, n)) if rows is not None]
+        if len(held) < len(reads):      # some in row order: the rest follow
+            self._on_shard_threads(lambda h: copy(*h), held, n)
+            return cleaned, 0
+        by_cols = np.empty(shape, out.dtype, order="F")
+        edges = [shape[1] * k // n for k in range(n + 1)]
+
+        def copy_block(block):
+            c0, c1 = edges[block], edges[block + 1]
+            with obs_tracing.span("d2h_block_copy", wid=wid, block=block):
+                for (_, _, lo, hi), rows in held:
+                    by_cols[lo:hi, c0:c1] = rows[:hi - lo, c0:c1]
+
+        self._on_shard_threads(copy_block, range(n), n)
+        return by_cols, n
+
+    def _on_shard_threads(self, fn, items, n):
+        """`fn(item)` for each of `items` on the plan's shard threads,
+        started at the first use, `n` of them; the results in order. A
+        failed call fails the batch."""
         with self._shard_pool_lock:
             if self._shard_pool is None:
                 self._shard_pool = ThreadPoolExecutor(
-                    len(shards), thread_name_prefix=SHARD_THREAD)
-            futs = [self._shard_pool.submit(read, *r) for r in reads]
+                    n, thread_name_prefix=SHARD_THREAD)
+            futs = [self._shard_pool.submit(fn, x) for x in items]
         wait(futs)
-        for f in futs:
-            f.result()                  # a failed shard fails the batch
-        return cleaned
+        return [f.result() for f in futs]
 
     def _close_shard_pool(self):
         """End the shard threads (after the last readback that uses them)."""
